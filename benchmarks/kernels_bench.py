@@ -62,10 +62,11 @@ def run(emit):
 
     a = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)
-    t_k = _time(lambda x, y: ops.covenant_matmul(x, y, blocks=(128, 128, 128)),
+    t_k = _time(lambda x, y: ops.covenant_matmul(x, y, blocks=(128, 128, 128),
+                                            interpret=True),
                 a, b)
     t_r = _time(lambda x, y: ref.matmul_ref(x, y), a, b)
-    got = ops.covenant_matmul(a, b, blocks=(128, 128, 128))
+    got = ops.covenant_matmul(a, b, blocks=(128, 128, 128), interpret=True)
     np.testing.assert_allclose(got, ref.matmul_ref(a, b), atol=1e-3)
     emit(f"kernels/matmul_256_interp,{t_k:.0f},ref_us={t_r:.0f} allclose=1")
 
@@ -73,8 +74,8 @@ def run(emit):
     kk = jnp.asarray(rng.standard_normal((1, 2, 128, 64)), jnp.float32)
     vv = jnp.asarray(rng.standard_normal((1, 2, 128, 64)), jnp.float32)
     t_k = _time(lambda x, y, z: ops.covenant_attention(
-        x, y, z, blocks=(64, 64)), q, kk, vv)
-    got = ops.covenant_attention(q, kk, vv, blocks=(64, 64))
+        x, y, z, blocks=(64, 64), interpret=True), q, kk, vv)
+    got = ops.covenant_attention(q, kk, vv, blocks=(64, 64), interpret=True)
     np.testing.assert_allclose(got, ref.attention_ref(q, kk, vv), atol=2e-3)
     emit(f"kernels/flash_attn_interp,{t_k:.0f},allclose=1")
 
@@ -83,8 +84,9 @@ def run(emit):
     A = -jnp.asarray(rng.uniform(0.5, 2.0, (4,)), jnp.float32)
     B = jnp.asarray(rng.standard_normal((1, 64, 2, 8)), jnp.float32)
     C = jnp.asarray(rng.standard_normal((1, 64, 2, 8)), jnp.float32)
-    t_k = _time(lambda *args: ops.covenant_ssd(*args, chunk=16), x, dt, A, B, C)
-    got = ops.covenant_ssd(x, dt, A, B, C, chunk=16)
+    t_k = _time(lambda *args: ops.covenant_ssd(*args, chunk=16, interpret=True),
+                x, dt, A, B, C)
+    got = ops.covenant_ssd(x, dt, A, B, C, chunk=16, interpret=True)
     np.testing.assert_allclose(got, ref.ssd_ref(x, dt, A, B, C), atol=2e-3)
     emit(f"kernels/ssd_scan_interp,{t_k:.0f},allclose=1")
 

@@ -16,7 +16,7 @@ import jax
 from repro import configs
 from repro.data import SyntheticLM
 from repro.launch.layers import layer_report
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.optim import adamw, cosine_schedule
 from repro.runtime import make_train_step, train_loop
@@ -43,7 +43,7 @@ def main() -> None:
     print(layer_report(cfg, tokens=8 * 256, target=args.accel_target))
 
     mesh = make_host_mesh()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init_params(jax.random.PRNGKey(0))
         opt = adamw(cosine_schedule(1e-3, 30, args.steps))
         opt_state = opt.init(params)

@@ -185,13 +185,20 @@ TPU_V5E = dict(
     ici_bw_per_link=50e9,     # B/s per link (bidirectional counted once)
     hbm_bytes=16 * 2**30,
     vmem_bytes=128 * 2**20,
+    # scoped VMEM each Covenant GEMM kernel asks Mosaic for
+    # (``CompilerParams(vmem_limit_bytes=...)``; v5e's default is 16 MiB)
+    vmem_limit_bytes=32 * 2**20,
     clock_hz=940e6,
 )
 
 # * HBM -> VMEM edge bandwidth: 819 GB/s / 940 MHz ~= 871 B/cycle => 7168
 #   bits per 'transfer op' (128 lanes * 56 bits; bandwidth only drives
 #   cost, not correctness).
-# * VMEM: (8,128) f32 native tile = 4096 B addressable element.
+# * VMEM: (8,128) f32 native tile = 4096 B addressable element.  The tiler
+#   places one copy of each tile, but a kernel holds three within its
+#   scoped limit: Pallas double-buffers every window, and Mosaic keeps a
+#   working copy of the left operand's window besides (measured by
+#   compiling for v5e).  So the ACG's VMEM is a third of that limit.
 # * MXU: 128x128 systolic bf16 GEMM; VPU: 8x128 f32 vector ALU.
 TPU_V5E_SPEC = acg_spec(
     "tpu_v5e",
@@ -200,7 +207,7 @@ TPU_V5E_SPEC = acg_spec(
              depth=(16 * 2**30 * 8) // (256 * 32), offchip=True),
         # elem = 32 bits * 1024 banks = 4096 B = one (8,128) f32 tile
         smem("VMEM", data_width=32, banks=1024,
-             depth=(128 * 2**20) // 4096),
+             depth=TPU_V5E["vmem_limit_bytes"] // 3 // 4096),
         smem("SMEM", data_width=32, banks=1, depth=4096),
     ],
     computes=[

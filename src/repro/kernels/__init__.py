@@ -1,7 +1,7 @@
 """Pallas TPU kernels scheduled by the Covenant tiler (DESIGN.md §3).
 
-``ops`` is the public API (padding + Covenant BlockSpecs + CPU interpret
-fallback); ``ref`` holds the pure-jnp oracles every kernel is tested
+``ops`` is the public API (padding + Covenant BlockSpecs; interpret mode
+only when asked for); ``ref`` holds the pure-jnp oracles every kernel is tested
 against; ``tiling`` is the Algorithm-1 -> BlockSpec bridge.
 """
 from . import flash_attention, matmul, ops, ref, ssd_scan, tiling

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -112,7 +111,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, scale: float, block_kv: int):
-    kj = pl.program_id(1)
+    b, kj = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kj == 0)
     def _init():
@@ -124,7 +123,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
     k = k_ref[0].astype(jnp.float32)           # (bkv, d)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     kpos = kj * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kpos < len_ref[0]
+    mask = kpos < len_ref[b]
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -167,7 +166,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, hg, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1,), lambda b, j: (b,), memory_space=pltpu.SMEM),
+            # all (BKV,) lengths sit in SMEM; the kernel indexes its row
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, hg, d), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -176,7 +176,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((hg, 1), jnp.float32),
             pltpu.VMEM((hg, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, kv_len)
@@ -283,7 +283,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=None,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, dout, lse, delta)
@@ -300,7 +300,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=None,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                         pltpu.VMEM((block_kv, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, dout, lse, delta)
@@ -337,7 +337,7 @@ def flash_attention_fwd_lse(q, k, v, *, causal=True, window=None, scale=None,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

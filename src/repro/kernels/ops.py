@@ -1,13 +1,11 @@
-"""Public kernel API: padding, block selection, CPU-interpret fallback.
+"""Public kernel API: padding and block selection around the Pallas kernels.
 
-``interpret`` defaults to True on CPU hosts (this container) and False on
-real TPU backends; models call these wrappers, never the kernels directly.
-Block geometry defaults to the Covenant tiler's Algorithm-1 choice
-(``tiling.gemm_blocks`` / ``attention_blocks``).
+The kernels are compiled for the TPU by Mosaic; ``interpret=True`` runs
+them in the Pallas interpreter instead, and only a caller that asks for it
+gets it (the CPU tests do).  Block geometry defaults to the Covenant
+tiler's Algorithm-1 choice (``tiling.gemm_blocks`` / ``attention_blocks``).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,15 +14,7 @@ from . import ref as _ref
 from .flash_attention import flash_attention as _fa, flash_decode as _fd
 from .matmul import matmul as _mm
 from .ssd_scan import ssd_chunk_scan as _ssd
-from .tiling import MXU, SUBLANE, attention_blocks, gemm_blocks
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret(flag) -> bool:
-    return (not _on_tpu()) if flag is None else flag
+from .tiling import SUBLANE, attention_blocks, gemm_blocks
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -37,14 +27,13 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, pads)
 
 
-def covenant_matmul(a: jax.Array, b: jax.Array, *, out_dtype=None,
+def covenant_matmul(a: jax.Array, b: jax.Array, *,
                     blocks: tuple[int, int, int] | None = None,
-                    interpret: bool | None = None) -> jax.Array:
-    """GEMM with Covenant-tiled BlockSpecs; pads to block multiples."""
+                    interpret: bool = False) -> jax.Array:
+    """GEMM with Covenant-tiled BlockSpecs; pads to block multiples.
+    Returns f32 for float inputs, i32 for int8."""
     m, k = a.shape
     _, n = b.shape
-    out_dtype = out_dtype or (
-        jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer) else jnp.float32)
     if blocks is None:
         in_dt = "i8" if jnp.issubdtype(a.dtype, jnp.integer) else "bf16"
         blocks = gemm_blocks(m, n, k, in_dtype=in_dt)
@@ -52,7 +41,7 @@ def covenant_matmul(a: jax.Array, b: jax.Array, *, out_dtype=None,
     ap = _pad_to(_pad_to(a, 0, bm), 1, bk)
     bp = _pad_to(_pad_to(b, 0, bk), 1, bn)
     out = _mm(ap, bp, block_m=bm, block_n=bn, block_k=bk,
-              out_dtype=out_dtype, interpret=_interpret(interpret))
+              interpret=interpret)
     return out[:m, :n]
 
 
@@ -60,7 +49,7 @@ def covenant_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool = True, window: int | None = None,
                        scale: float | None = None,
                        blocks: tuple[int, int] | None = None,
-                       interpret: bool | None = None) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """GQA flash attention.  q: (B,Hq,Sq,D), k/v: (B,Hkv,Sk,D)."""
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
@@ -77,7 +66,7 @@ def covenant_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vf = v.reshape(b * hq, -1, d)
     out = _fa(qf, kf, vf, causal=causal, window=window, scale=scale,
               block_q=bq, block_kv=bkv, q_offset=kf.shape[1] - sq,
-              interpret=_interpret(interpret))
+              interpret=interpret)
     return out[:, :sq].reshape(b, hq, sq, d)
 
 
@@ -85,7 +74,7 @@ def covenant_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               kv_len: jax.Array, *,
                               scale: float | None = None,
                               block_kv: int = 512,
-                              interpret: bool | None = None) -> jax.Array:
+                              interpret: bool = False) -> jax.Array:
     """One-token GQA decode.  q: (B,Hq,D), cache k/v: (B,Hkv,S,D),
     kv_len: (B,).  Returns (B,Hq,D)."""
     b, hq, d = q.shape
@@ -96,7 +85,7 @@ def covenant_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     vf = v.reshape(b * hkv, s, d)
     lens = jnp.repeat(kv_len, hkv)
     out = _fd(qg, kf, vf, lens, scale=scale, block_kv=min(block_kv, s),
-              interpret=_interpret(interpret))
+              interpret=interpret)
     return out.reshape(b, hq, d)
 
 
@@ -104,7 +93,7 @@ def covenant_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                  C: jax.Array, *, chunk: int = 64,
                  init_state: jax.Array | None = None,
                  return_state: bool = False,
-                 interpret: bool | None = None):
+                 interpret: bool = False):
     """Mamba2 SSD over (b, s, h, p) inputs with (b, s, g, n) B/C."""
     b, s, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
@@ -122,7 +111,7 @@ def covenant_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     if init_state is not None:
         st0 = init_state.reshape(b * h, p, n).swapaxes(1, 2)  # (BH,N,P)
     y, fin = _ssd(xf, dtf, Af, Bf, Cf, chunk=ck, init_state=st0,
-                  interpret=_interpret(interpret))
+                  interpret=interpret)
     y = y[:, :s].reshape(b, h, s, p).transpose(0, 2, 1, 3)
     if return_state:
         return y, fin.swapaxes(1, 2).reshape(b, h, p, n)

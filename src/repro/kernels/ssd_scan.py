@@ -24,39 +24,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
+def _ssd_chunk_kernel(x_ref, dt_ref, cum_ref, cum_t_ref, decay_ref, b_ref,
+                      c_ref, y_ref, state_ref):
+    """One (bh, chunk) cell: intra-chunk output + end-of-chunk state.
 
-def _ssd_chunk_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
-                      y_ref, state_ref, dsum_ref):
-    """One (bh, chunk) cell: intra-chunk output + end-of-chunk state."""
+    ``cum`` is the chunk's inclusive cumsum of dt*A, given both as a
+    column (L, 1) and as a row (1, L) so that no relayout happens here;
+    ``decay`` is exp(cum_L - cum), the decay of each step to the chunk's
+    end."""
     x = x_ref[0].astype(jnp.float32)      # (L, P)
-    dt = dt_ref[0].astype(jnp.float32)    # (L, 1) — lane-padded
+    dt = dt_ref[0].astype(jnp.float32)    # (L, 1)
+    cum = cum_ref[0]                      # (L, 1)
+    cum_t = cum_t_ref[0]                  # (1, L)
+    decay_to_end = decay_ref[0]           # (L, 1)
     bmat = b_ref[0].astype(jnp.float32)   # (L, N)
     cmat = c_ref[0].astype(jnp.float32)   # (L, N)
-    a = a_ref[0]                          # scalar decay rate (f32, SMEM)
-
-    da = dt[:, 0] * a                     # (L,) log-decay increments
-    cum = jnp.cumsum(da)                  # inclusive cumsum
     L = x.shape[0]
     # Γ[i,j] = exp(cum_i - cum_j) for j <= i (segment decay), else 0.
     # Mask inside the exp so the masked branch cannot overflow.
-    seg = cum[:, None] - cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    gamma = jnp.exp(jnp.where(jj <= ii, seg, -1e30))
+    gamma = jnp.exp(jnp.where(jj <= ii, cum - cum_t, -1e30))
 
     # Y_intra = ((C B^T) ⊙ Γ) (Δ ⊙ X)
     att = jnp.dot(cmat, bmat.T, preferred_element_type=jnp.float32) * gamma
-    xdt = x * dt[:, :1]
+    xdt = x * dt
     y_ref[0] = jnp.dot(att, xdt, preferred_element_type=jnp.float32
                        ).astype(y_ref.dtype)
 
     # S_c = (B ⊙ exp(cum_L - cum))^T (Δ ⊙ X)   -> (N, P)
-    decay_to_end = jnp.exp(cum[-1] - cum)[:, None]
     state_ref[0] = jnp.dot((bmat * decay_to_end).T, xdt,
                            preferred_element_type=jnp.float32)
-    dsum_ref[0, 0] = cum[-1]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -67,42 +66,46 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     """Head-batched SSD: x (BH,S,P), dt (BH,S), A (BH,), B/C (BH,S,N).
 
     Returns (y (BH,S,P), final_state (BH,N,P)).  S % chunk == 0 (ops.py
-    pads).  The chunk-local heavy stages run in the Pallas kernel; the
-    cross-chunk combination is jnp.
+    pads); on the TPU ``chunk`` is a multiple of 128 or all of S.  The
+    chunk-local heavy stages run in the Pallas kernel; the per-chunk
+    cumsum and the cross-chunk combination are jnp.
     """
     bh, s, p = x.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)
     nck = s // chunk
-    dt2 = dt[..., None]  # (BH,S,1) lane dim for VMEM tiling
+    dtf = dt.astype(jnp.float32).reshape(bh, nck, chunk)
+    cum_in = jnp.cumsum(dtf * A.astype(jnp.float32)[:, None, None], axis=-1)
+    cum = cum_in.reshape(bh, s)
+    decay = jnp.exp(cum_in[..., -1:] - cum_in).reshape(bh, s, 1)
 
-    y_intra, states, dsums = pl.pallas_call(
+    y_intra, states = pl.pallas_call(
         _ssd_chunk_kernel,
         grid=(bh, nck),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda b, c: (b, 0, c)),
+            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1,), lambda b, c: (b,), memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, n, p), lambda b, c: (b * nck + c, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, c: (b * nck + c, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, p), jnp.float32),
             jax.ShapeDtypeStruct((bh * nck, n, p), jnp.float32),
-            jax.ShapeDtypeStruct((bh * nck, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x, dt2, B, C, A.astype(jnp.float32))
+    )(x, dt[..., None], cum[..., None], cum[:, None, :], decay, B, C)
 
     states = states.reshape(bh, nck, n, p)
-    dsums = dsums.reshape(bh, nck)
+    dsums = cum_in[..., -1]                   # (BH, nck) chunk log-decay
 
     # inter-chunk recurrence over ncache states: H_c = e^{dsum_c} H_{c-1} + S_c
     def comb(left, right):
@@ -124,8 +127,6 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         hstates[:, :-1]], axis=1)  # (BH, ncache, N, P)
 
     # Y_inter[t] = exp(cum_t) * C_t @ H_prev(chunk(t))
-    dtf = dt.astype(jnp.float32).reshape(bh, nck, chunk)
-    cum_in = jnp.cumsum(dtf * A.astype(jnp.float32)[:, None, None], axis=-1)
     gamma_start = jnp.exp(cum_in)  # (BH,ncache,L)
     Cc = C.astype(jnp.float32).reshape(bh, nck, chunk, n)
     y_inter = jnp.einsum("bcln,bcnp->bclp", Cc, h_prev) * \

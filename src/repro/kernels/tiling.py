@@ -1,11 +1,12 @@
 """Covenant -> Pallas bridge: the paper's Algorithm-1 tiler selects the
 BlockSpec geometry for our TPU kernels (DESIGN.md §3, deviation D1).
 
-The TPU-v5e ACG models VMEM capacity and the MXU's (128,128,128) GEMM
-capability.  ``gemm_blocks`` runs the Covenant pipeline (placement, compute
-mapping, Algorithm-1 tiling enumeration + cost-based selection) on a GEMM
-codelet of the requested problem size and returns the chosen tile as Pallas
-block sizes.  The paper's alignment rule — "data chunks are divisible by the
+The TPU-v5e ACG models the VMEM a kernel may fill (a third of the scoped
+limit it asks for, see ``core/targets.py``) and the MXU's (128,128,128)
+GEMM capability.  ``gemm_blocks`` runs the Covenant pipeline (placement,
+compute mapping, Algorithm-1 tiling enumeration + cost-based selection) on
+a GEMM codelet of the requested problem size and returns the chosen tile
+as Pallas block sizes.  The paper's alignment rule — "data chunks are divisible by the
 size of an addressable element" (§2.1.1) — becomes the (8,128) / MXU-128
 alignment filter applied to the candidate set.
 """
@@ -35,8 +36,7 @@ def _align_score(t: dict[str, int], dims: dict[str, int]) -> tuple:
 
 @functools.lru_cache(maxsize=512)
 def gemm_blocks(m: int, n: int, k: int, in_dtype: str = "bf16",
-                acc_dtype: str = "f32",
-                vmem_budget_frac: float = 1.0) -> tuple[int, int, int]:
+                acc_dtype: str = "f32") -> tuple[int, int, int]:
     """(block_m, block_n, block_k) for an (m,n,k) GEMM, chosen by the
     Covenant tiler against the TPU-v5e ACG."""
     acg = targets.tpu_v5e_acg()

@@ -47,7 +47,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
              zero_serve_params: bool | None = None) -> dict:
     """Lower + compile one cell; returns the roofline-ready record."""
     from repro.launch import specs
-    from repro.launch.mesh import use_mesh
     from repro.models.common import configure_activation_sharding
     from repro.roofline.collect import collect_compiled
 
@@ -60,7 +59,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     mesh = _mesh(mesh_kind)
     t0 = time.time()
     cfg = configs.get_config(arch)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
         heads = "model" if (cfg.n_heads and
                             cfg.n_heads % mesh.shape["model"] == 0) else None

@@ -1,10 +1,11 @@
-"""Serving driver: batched prefill + decode with a continuous request
-queue.  ``python -m repro.launch.serve --arch qwen3-0.6b --smoke``.
+"""Serving driver: batched prefill + greedy decode.
+``python -m repro.launch.serve --arch qwen3-0.6b``.
 
-Implements a minimal production serving loop: a batch of requests is
-prefixed (prefill), then decoded step-by-step with the KV cache donated
-between steps; finished sequences (EOS or max tokens) are retired and
-their slots refilled from the queue (continuous batching).
+Requests are served in fixed batches: each batch is prefilled under
+``jit``, then decoded token by token with the KV cache donated between
+steps, until every sequence has hit EOS or ``--max-new`` tokens.  The mesh
+is this host's devices; ``--smoke`` shrinks the config so that it runs on a
+CPU.
 
 Layer compilation is routed through the unified driver: before serving,
 the model's decode-shape GEMMs are compiled with ``repro.compile`` for
@@ -24,12 +25,13 @@ import numpy as np
 
 import repro
 from repro import configs
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               use_mesh)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Serve ``--requests`` random prompts; returns the counts printed."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(configs.ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -46,7 +48,8 @@ def main() -> None:
     ap.add_argument("--accel-search", action="store_true",
                     help="schedule-search the layer compiles "
                          "(CompileOptions(search=...))")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     model = get_model(cfg)
@@ -57,11 +60,12 @@ def main() -> None:
             if args.accel_search else None)
         print(layer_report(cfg, tokens=args.batch,
                            target=args.accel_target, options=opts))
-    mesh = make_host_mesh() if args.smoke else make_production_mesh()
+    mesh = make_host_mesh()
     rng = np.random.default_rng(args.seed)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init_params(jax.random.PRNGKey(args.seed))
+        prefill = jax.jit(model.prefill, donate_argnums=(2,))
         decode = jax.jit(model.decode_step, donate_argnums=(2,))
 
         def new_prompt():
@@ -82,7 +86,7 @@ def main() -> None:
                     rng.standard_normal(shape_fn(bs, args.prompt_len)),
                     dtype)
             cache = model.init_cache(bs, args.max_len)
-            logits, cache = model.prefill(params, batch, cache)
+            logits, cache = prefill(params, batch, cache)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             done = np.zeros(bs, bool)
             for _ in range(args.max_new):
@@ -96,6 +100,7 @@ def main() -> None:
         dt = time.perf_counter() - t0
         print(f"[serve] {cfg.name}: {served} requests, {total_tokens} new "
               f"tokens in {dt:.2f}s ({total_tokens / dt:.1f} tok/s)")
+    return {"requests": served, "new_tokens": total_tokens, "seconds": dt}
 
 
 if __name__ == "__main__":

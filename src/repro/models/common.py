@@ -33,16 +33,10 @@ _ACT_AXES: dict = {"batch": None, "seq": None, "heads": None, "vocab": None}
 
 
 def current_mesh():
-    """The ambient mesh, across jax versions: ``jax.sharding
-    .get_abstract_mesh`` (new) or the thread-resources physical mesh set by
-    ``with mesh:`` (0.4.x).  Returns None when no mesh is active."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        m = getter()
-        return None if m is None or getattr(m, "empty", False) else m
-    from jax._src import mesh as _mesh
-    pm = _mesh.thread_resources.env.physical_mesh
-    return None if pm.empty else pm
+    """The ambient mesh set by ``jax.set_mesh``, or None when there is
+    none."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def configure_activation_sharding(batch_axes=None, seq_axes=None,

@@ -15,19 +15,10 @@ need explicit control and are provided here as shard_map primitives:
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-# jax moved shard_map around across releases: modern jax exports the
-# function at top level; 0.4.x keeps it in jax.experimental.shard_map (and
-# in some versions ``jax.shard_map`` resolves to the *module*).
-try:
-    from jax import shard_map as _shard_map
-    shard_map = _shard_map if callable(_shard_map) else _shard_map.shard_map
-except (ImportError, AttributeError):
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30

@@ -121,4 +121,7 @@ def test_tpu_v5e_acg_mxu_alignment():
     vmem = g.memory("VMEM")
     # addressable element = one (8,128) f32 tile = 4096 B
     assert vmem.elem_bits // 8 == 4096
-    assert vmem.capacity_bytes == 128 * 2**20
+    # a third of the kernels' scoped VMEM limit: every window is
+    # double-buffered and Mosaic keeps a working copy besides
+    limit = targets.TPU_V5E["vmem_limit_bytes"]
+    assert limit - 3 * vmem.elem_bits // 8 < 3 * vmem.capacity_bytes <= limit

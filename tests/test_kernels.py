@@ -4,6 +4,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core.targets import TPU_V5E
 from repro.kernels import ops, ref
 from repro.kernels.tiling import attention_blocks, gemm_blocks
 
@@ -25,9 +26,10 @@ def test_gemm_blocks_are_valid(mnk):
     m, n, k = mnk
     bm, bn, bk = gemm_blocks(m, n, k)
     assert bm >= 1 and bn >= 1 and bk >= 1
-    # VMEM fit for the working set the kernel stages (a, b, acc blocks)
+    # VMEM fit: one copy of the (a, b, f32 out) windows within the tiler's
+    # third of the kernel's scoped limit (see core/targets.py)
     bytes_ = (bm * bk + bk * bn) * 2 + bm * bn * 4
-    assert bytes_ <= 128 * 2**20
+    assert 3 * bytes_ <= TPU_V5E["vmem_limit_bytes"]
     # MXU-friendly unless the problem is smaller than one tile
     if n >= 128:
         assert bn % 128 == 0
@@ -53,7 +55,7 @@ def test_matmul_float(mnk, dtype):
     m, n, k = mnk
     a = randn(m, k, dtype=dtype)
     b = randn(k, n, dtype=dtype)
-    got = ops.covenant_matmul(a, b, blocks=(32, 128, 128))
+    got = ops.covenant_matmul(a, b, blocks=(32, 128, 128), interpret=True)
     want = ref.matmul_ref(a, b)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -66,7 +68,7 @@ def test_matmul_int8(mnk):
     m, n, k = mnk
     a = jnp.asarray(rng.integers(-8, 8, (m, k)), jnp.int8)
     b = jnp.asarray(rng.integers(-8, 8, (k, n)), jnp.int8)
-    got = ops.covenant_matmul(a, b, blocks=(32, 128, 128))
+    got = ops.covenant_matmul(a, b, blocks=(32, 128, 128), interpret=True)
     want = np.asarray(a, np.int32) @ np.asarray(b, np.int32)
     np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -74,7 +76,7 @@ def test_matmul_int8(mnk):
 def test_matmul_covenant_default_blocks():
     a = randn(300, 200)
     b = randn(200, 150)
-    got = ops.covenant_matmul(a, b)  # tiler-chosen blocks
+    got = ops.covenant_matmul(a, b, interpret=True)  # tiler-chosen blocks
     np.testing.assert_allclose(got, ref.matmul_ref(a, b), atol=1e-3)
 
 
@@ -98,7 +100,8 @@ def test_flash_attention_matches_ref(case):
     k = randn(case["b"], case["hkv"], case["sk"], case["d"])
     v = randn(case["b"], case["hkv"], case["sk"], case["d"])
     got = ops.covenant_attention(q, k, v, causal=case["causal"],
-                                 window=case["win"], blocks=(32, 128))
+                                 window=case["win"], blocks=(32, 128),
+                                 interpret=True)
     want = ref.attention_ref(q, k, v, causal=case["causal"],
                              window=case["win"])
     np.testing.assert_allclose(got, want, atol=2e-3)
@@ -109,7 +112,7 @@ def test_flash_attention_dtypes(dtype):
     q = randn(1, 2, 64, 32, dtype=dtype)
     k = randn(1, 2, 64, 32, dtype=dtype)
     v = randn(1, 2, 64, 32, dtype=dtype)
-    got = ops.covenant_attention(q, k, v, blocks=(32, 64))
+    got = ops.covenant_attention(q, k, v, blocks=(32, 64), interpret=True)
     want = ref.attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
@@ -121,7 +124,8 @@ def test_flash_decode_matches_ref():
     k = randn(b, hkv, s, d)
     v = randn(b, hkv, s, d)
     kv_len = jnp.asarray([100, 256, 17])
-    got = ops.covenant_decode_attention(q, k, v, kv_len, block_kv=64)
+    got = ops.covenant_decode_attention(q, k, v, kv_len, block_kv=64,
+                                          interpret=True)
     want = ref.attention_ref(q[:, :, None, :], k, v, causal=False,
                              kv_len=kv_len)[:, :, 0, :]
     np.testing.assert_allclose(got, want, atol=2e-3)
@@ -130,9 +134,9 @@ def test_flash_decode_matches_ref():
 def test_flash_window_equals_dense_when_window_covers_all():
     q, k, v = randn(1, 2, 64, 16), randn(1, 2, 64, 16), randn(1, 2, 64, 16)
     wide = ops.covenant_attention(q, k, v, causal=True, window=4096,
-                                  blocks=(32, 64))
+                                  blocks=(32, 64), interpret=True)
     dense = ops.covenant_attention(q, k, v, causal=True, window=None,
-                                   blocks=(32, 64))
+                                   blocks=(32, 64), interpret=True)
     np.testing.assert_allclose(wide, dense, atol=1e-5)
 
 
@@ -157,7 +161,7 @@ def test_ssd_matches_sequential_ref(case):
     B = randn(b, s, g, n)
     C = randn(b, s, g, n)
     got, st = ops.covenant_ssd(x, dt, A, B, C, chunk=case["chunk"],
-                               return_state=True)
+                               return_state=True, interpret=True)
     want, wst = ref.ssd_ref(x, dt, A, B, C, return_state=True)
     np.testing.assert_allclose(got, want, atol=2e-3)
     np.testing.assert_allclose(st, wst, atol=2e-3)
@@ -171,13 +175,14 @@ def test_ssd_init_state_continuation():
     A = -jnp.asarray(rng.uniform(0.5, 2.0, (h,)), jnp.float32)
     B, C = randn(b, s, g, n), randn(b, s, g, n)
     y_full, st_full = ops.covenant_ssd(x, dt, A, B, C, chunk=16,
-                                       return_state=True)
+                                       return_state=True, interpret=True)
     half = s // 2
     y1, st1 = ops.covenant_ssd(x[:, :half], dt[:, :half], A, B[:, :half],
-                               C[:, :half], chunk=16, return_state=True)
+                               C[:, :half], chunk=16, return_state=True,
+                               interpret=True)
     y2, st2 = ops.covenant_ssd(x[:, half:], dt[:, half:], A, B[:, half:],
                                C[:, half:], chunk=16, init_state=st1,
-                               return_state=True)
+                               return_state=True, interpret=True)
     np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), y_full, atol=2e-3)
     np.testing.assert_allclose(st2, st_full, atol=2e-3)
 
@@ -189,9 +194,11 @@ def test_ssd_decay_reduces_state_influence():
     dt = jnp.full((b, s, h), 0.1, jnp.float32)
     B, C = randn(b, s, g, n), randn(b, s, g, n)
     _, st_slow = ops.covenant_ssd(x, dt, jnp.asarray([-0.1, -0.1]), B, C,
-                                  chunk=16, return_state=True)
+                                  chunk=16, return_state=True,
+                                  interpret=True)
     _, st_fast = ops.covenant_ssd(x, dt, jnp.asarray([-8.0, -8.0]), B, C,
-                                  chunk=16, return_state=True)
+                                  chunk=16, return_state=True,
+                                  interpret=True)
     assert float(jnp.linalg.norm(st_fast)) < float(jnp.linalg.norm(st_slow))
 
 

@@ -51,19 +51,19 @@ _MINI_DRYRUN = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType
 from repro.launch import specs
-from repro.launch.mesh import use_mesh
 from repro import configs
 from repro.models.common import configure_activation_sharding
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 # shrink shapes for speed: fabricate a small ShapeSpec
 configs.SHAPES["mini_train"] = configs.ShapeSpec("mini_train", "train", 64, 8)
 configs.SHAPES["mini_decode"] = configs.ShapeSpec("mini_decode", "decode",
                                                   64, 8)
 ok = []
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     configure_activation_sharding(("data",), "model", None, None)
     for arch, shape, kind in [
         ("qwen3-0.6b", "mini_train", "train"),

@@ -261,11 +261,12 @@ _COLLECTIVE_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType
 from repro.runtime.collectives import compressed_psum, sharded_decode_attention
 from repro.kernels.ref import attention_ref
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 
 # compressed psum ~= plain psum
 g = {"w": jnp.asarray(np.random.default_rng(0).standard_normal((8, 16)),
