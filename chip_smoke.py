@@ -19,7 +19,6 @@ import functools
 import json
 import os
 import sys
-import time
 import traceback
 from typing import Callable
 
@@ -381,7 +380,6 @@ def main(argv: list[str] | None = None) -> int:
 
     from repro.launch.compile_cache import enable_compile_cache
     print(f"[cache] {enable_compile_cache()}", flush=True)
-    t0 = time.perf_counter()
     failed = []
     phases = [train_phase] if args.chips == 4 else \
         [kernel_phase, serve_phase, serve_reference_phase]
@@ -391,9 +389,6 @@ def main(argv: list[str] | None = None) -> int:
         except Exception:
             traceback.print_exc()
             failed.append(phase.__name__)
-        print(f"[time] {phase.__name__} done at "
-              f"{time.perf_counter() - t0:.1f}s (information only)",
-              flush=True)
     if failed:
         print(f"chip_smoke: FAILED {failed}", flush=True)
         return 1

@@ -82,14 +82,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sk_pad = -(-sk // block_kv) * block_kv
     if sk_pad != sk:
         pad = [(0, 0), (0, sk_pad - sk), (0, 0)]
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
+        with jax.named_scope("pad"):
+            k = jnp.pad(k, pad)
+            v = jnp.pad(v, pad)
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_kv=block_kv, seq_k=sk,
         q_offset=(sk - sq) if q_offset is None else q_offset)
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(bh, sq // block_q, sk_pad // block_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -156,11 +158,13 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = scale if scale is not None else (d ** -0.5)
     s_pad = -(-s // block_kv) * block_kv
     if s_pad != s:
-        k = jnp.pad(k, [(0, 0), (0, s_pad - s), (0, 0)])
-        v = jnp.pad(v, [(0, 0), (0, s_pad - s), (0, 0)])
+        with jax.named_scope("pad"):
+            k = jnp.pad(k, [(0, 0), (0, s_pad - s), (0, 0)])
+            v = jnp.pad(v, [(0, 0), (0, s_pad - s), (0, 0)])
     kernel = functools.partial(_decode_kernel, scale=scale, block_kv=block_kv)
     return pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid=(bkv, s_pad // block_kv),
         in_specs=[
             pl.BlockSpec((1, hg, d), lambda b, j: (b, 0, 0)),

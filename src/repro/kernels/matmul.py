@@ -43,6 +43,7 @@ def matmul(a: jax.Array, b: jax.Array, *, block_m: int, block_n: int,
     acc_dtype = jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer) else jnp.float32
     return pl.pallas_call(
         _matmul_kernel,
+        name="matmul",
         grid=(m // block_m, n // block_n, k // block_k),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),

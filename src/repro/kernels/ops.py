@@ -4,6 +4,19 @@ The kernels are compiled for the TPU by Mosaic; ``interpret=True`` runs
 them in the Pallas interpreter instead, and only a caller that asks for it
 gets it (the CPU tests do).  Block geometry defaults to the Covenant
 tiler's Algorithm-1 choice (``tiling.gemm_blocks`` / ``attention_blocks``).
+
+Each wrapper runs under a named scope of its own name, and each of its
+steps that is no kernel under one step scope, so that every op it makes
+carries ``<wrapper>/<step>`` in its HLO ``op_name`` and a profiler trace
+can attribute it (``ssd_chunk_scan`` adds its own steps):
+
+* ``pad``: operands padded to block multiples;
+* ``unpad``: the output sliced back to the caller's extent;
+* ``repeat``: K/V, lengths, B/C or A repeated over heads;
+* ``layout``: reshapes and transposes into and out of the kernels'
+  operand layouts.
+
+The scopes are metadata: they change no op of the compiled program.
 """
 from __future__ import annotations
 
@@ -27,6 +40,18 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, pads)
 
 
+def _heads_first(t: jax.Array, chunk: int) -> jax.Array:
+    """(b, s, h, ...) -> (b·h, s', ...), s' the next multiple of ``chunk``:
+    the sequence padded (``pad``), then the heads moved before it
+    (``layout``)."""
+    with jax.named_scope("pad"):
+        t = _pad_to(t, 1, chunk)
+    with jax.named_scope("layout"):
+        t = jnp.swapaxes(t, 1, 2)
+        return t.reshape(-1, *t.shape[2:])
+
+
+@jax.named_scope("covenant_matmul")
 def covenant_matmul(a: jax.Array, b: jax.Array, *,
                     blocks: tuple[int, int, int] | None = None,
                     interpret: bool = False) -> jax.Array:
@@ -38,13 +63,16 @@ def covenant_matmul(a: jax.Array, b: jax.Array, *,
         in_dt = "i8" if jnp.issubdtype(a.dtype, jnp.integer) else "bf16"
         blocks = gemm_blocks(m, n, k, in_dtype=in_dt)
     bm, bn, bk = blocks
-    ap = _pad_to(_pad_to(a, 0, bm), 1, bk)
-    bp = _pad_to(_pad_to(b, 0, bk), 1, bn)
+    with jax.named_scope("pad"):
+        ap = _pad_to(_pad_to(a, 0, bm), 1, bk)
+        bp = _pad_to(_pad_to(b, 0, bk), 1, bn)
     out = _mm(ap, bp, block_m=bm, block_n=bn, block_k=bk,
               interpret=interpret)
-    return out[:m, :n]
+    with jax.named_scope("unpad"):
+        return out[:m, :n]
 
 
+@jax.named_scope("covenant_attention")
 def covenant_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool = True, window: int | None = None,
                        scale: float | None = None,
@@ -54,22 +82,31 @@ def covenant_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     if hkv != hq:
-        k = jnp.repeat(k, hq // hkv, axis=1)
-        v = jnp.repeat(v, hq // hkv, axis=1)
+        with jax.named_scope("repeat"):
+            k = jnp.repeat(k, hq // hkv, axis=1)
+            v = jnp.repeat(v, hq // hkv, axis=1)
     if blocks is None:
         bq, bkv = attention_blocks(sq, k.shape[2], d)
     else:
         bq, bkv = blocks
     bq = min(bq, -(-sq // SUBLANE) * SUBLANE)
-    qf = _pad_to(q.reshape(b * hq, sq, d), 1, bq)
-    kf = k.reshape(b * hq, -1, d)
-    vf = v.reshape(b * hq, -1, d)
+    with jax.named_scope("layout"):
+        qf = q.reshape(b * hq, sq, d)
+    with jax.named_scope("pad"):
+        qf = _pad_to(qf, 1, bq)
+    with jax.named_scope("layout"):
+        kf = k.reshape(b * hq, -1, d)
+        vf = v.reshape(b * hq, -1, d)
     out = _fa(qf, kf, vf, causal=causal, window=window, scale=scale,
               block_q=bq, block_kv=bkv, q_offset=kf.shape[1] - sq,
               interpret=interpret)
-    return out[:, :sq].reshape(b, hq, sq, d)
+    with jax.named_scope("unpad"):
+        out = out[:, :sq]
+    with jax.named_scope("layout"):
+        return out.reshape(b, hq, sq, d)
 
 
+@jax.named_scope("covenant_decode_attention")
 def covenant_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               kv_len: jax.Array, *,
                               scale: float | None = None,
@@ -80,42 +117,53 @@ def covenant_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     b, hq, d = q.shape
     _, hkv, s, _ = k.shape
     g = hq // hkv
-    qg = q.reshape(b * hkv, g, d)
-    kf = k.reshape(b * hkv, s, d)
-    vf = v.reshape(b * hkv, s, d)
-    lens = jnp.repeat(kv_len, hkv)
+    with jax.named_scope("layout"):
+        qg = q.reshape(b * hkv, g, d)
+        kf = k.reshape(b * hkv, s, d)
+        vf = v.reshape(b * hkv, s, d)
+    with jax.named_scope("repeat"):
+        lens = jnp.repeat(kv_len, hkv)
     out = _fd(qg, kf, vf, lens, scale=scale, block_kv=min(block_kv, s),
               interpret=interpret)
-    return out.reshape(b, hq, d)
+    with jax.named_scope("layout"):
+        return out.reshape(b, hq, d)
 
 
+@jax.named_scope("covenant_ssd")
 def covenant_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-                 C: jax.Array, *, chunk: int = 64,
+                 C: jax.Array, *, chunk: int = 256,
                  init_state: jax.Array | None = None,
                  return_state: bool = False,
                  interpret: bool = False):
-    """Mamba2 SSD over (b, s, h, p) inputs with (b, s, g, n) B/C."""
+    """Mamba2 SSD over (b, s, h, p) inputs with (b, s, g, n) B/C.  The
+    default ``chunk`` is Mamba2's ``chunk_size``; on the TPU it is a
+    multiple of 128 or at least ``s``."""
     b, s, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     rep = h // g
     ck = min(chunk, s)
-    spad = -(-s // ck) * ck
-    xf = _pad_to(x, 1, ck).transpose(0, 2, 1, 3).reshape(b * h, spad, p)
-    dtf = _pad_to(dt, 1, ck).transpose(0, 2, 1).reshape(b * h, spad)
-    Bh = jnp.repeat(B, rep, axis=2)
-    Ch = jnp.repeat(C, rep, axis=2)
-    Bf = _pad_to(Bh, 1, ck).transpose(0, 2, 1, 3).reshape(b * h, spad, n)
-    Cf = _pad_to(Ch, 1, ck).transpose(0, 2, 1, 3).reshape(b * h, spad, n)
-    Af = jnp.tile(A, b)
+    xf = _heads_first(x, ck)
+    dtf = _heads_first(dt, ck)
+    with jax.named_scope("repeat"):
+        Bh = jnp.repeat(B, rep, axis=2)
+        Ch = jnp.repeat(C, rep, axis=2)
+    Bf = _heads_first(Bh, ck)
+    Cf = _heads_first(Ch, ck)
+    with jax.named_scope("repeat"):
+        Af = jnp.tile(A, b)
     st0 = None
     if init_state is not None:
-        st0 = init_state.reshape(b * h, p, n).swapaxes(1, 2)  # (BH,N,P)
+        with jax.named_scope("layout"):
+            st0 = init_state.reshape(b * h, p, n).swapaxes(1, 2)  # (BH,N,P)
     y, fin = _ssd(xf, dtf, Af, Bf, Cf, chunk=ck, init_state=st0,
                   interpret=interpret)
-    y = y[:, :s].reshape(b, h, s, p).transpose(0, 2, 1, 3)
-    if return_state:
-        return y, fin.swapaxes(1, 2).reshape(b, h, p, n)
-    return y
+    with jax.named_scope("unpad"):
+        y = y[:, :s]
+    with jax.named_scope("layout"):
+        y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
+        if return_state:
+            return y, fin.swapaxes(1, 2).reshape(b, h, p, n)
+        return y
 
 
 # re-export oracles for convenience

@@ -60,7 +60,7 @@ def _ssd_chunk_kernel(x_ref, dt_ref, cum_ref, cum_t_ref, decay_ref, b_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-                   C: jax.Array, *, chunk: int = 64,
+                   C: jax.Array, *, chunk: int = 256,
                    init_state: jax.Array | None = None,
                    interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """Head-batched SSD: x (BH,S,P), dt (BH,S), A (BH,), B/C (BH,S,N).
@@ -68,19 +68,29 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     Returns (y (BH,S,P), final_state (BH,N,P)).  S % chunk == 0 (ops.py
     pads); on the TPU ``chunk`` is a multiple of 128 or all of S.  The
     chunk-local heavy stages run in the Pallas kernel; the per-chunk
-    cumsum and the cross-chunk combination are jnp.
+    cumsum and the cross-chunk combination are jnp, each stage under a
+    step scope as ``ops.py`` names its wrappers' steps: ``decay`` (the
+    cumsum and its exps), ``layout`` (the kernel's column and row
+    operands), ``carry`` (the recurrence across chunks) and ``inter`` (the
+    output of the states entering each chunk).
     """
     bh, s, p = x.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)
     nck = s // chunk
-    dtf = dt.astype(jnp.float32).reshape(bh, nck, chunk)
-    cum_in = jnp.cumsum(dtf * A.astype(jnp.float32)[:, None, None], axis=-1)
-    cum = cum_in.reshape(bh, s)
-    decay = jnp.exp(cum_in[..., -1:] - cum_in).reshape(bh, s, 1)
+    with jax.named_scope("decay"):
+        dtf = dt.astype(jnp.float32).reshape(bh, nck, chunk)
+        cum_in = jnp.cumsum(dtf * A.astype(jnp.float32)[:, None, None],
+                            axis=-1)
+        cum = cum_in.reshape(bh, s)
+        decay = jnp.exp(cum_in[..., -1:] - cum_in).reshape(bh, s, 1)
+    with jax.named_scope("layout"):
+        operands = (x, dt[..., None], cum[..., None], cum[:, None, :], decay,
+                    B, C)
 
     y_intra, states = pl.pallas_call(
         _ssd_chunk_kernel,
+        name="ssd_chunk_scan",
         grid=(bh, nck),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
@@ -102,37 +112,42 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x, dt[..., None], cum[..., None], cum[:, None, :], decay, B, C)
+    )(*operands)
 
-    states = states.reshape(bh, nck, n, p)
-    dsums = cum_in[..., -1]                   # (BH, nck) chunk log-decay
+    with jax.named_scope("carry"):
+        states = states.reshape(bh, nck, n, p)
+        dsums = cum_in[..., -1]               # (BH, nck) chunk log-decay
 
-    # inter-chunk recurrence over ncache states: H_c = e^{dsum_c} H_{c-1} + S_c
-    def comb(left, right):
-        dl, sl = left
-        dr, sr = right
-        return dl + dr, sr + sl * jnp.exp(dr)[..., None, None]
+        # inter-chunk recurrence over ncache states:
+        # H_c = e^{dsum_c} H_{c-1} + S_c
+        def comb(left, right):
+            dl, sl = left
+            dr, sr = right
+            return dl + dr, sr + sl * jnp.exp(dr)[..., None, None]
 
-    dcum, hstates = jax.lax.associative_scan(
-        comb, (dsums.swapaxes(0, 1), states.swapaxes(0, 1)))
-    hstates = hstates.swapaxes(0, 1)  # (BH, ncache, N, P) — end-of-chunk states
-    if init_state is not None:
-        carry = jnp.exp(dcum.swapaxes(0, 1))[..., None, None] * \
-            init_state[:, None].astype(jnp.float32)
-        hstates = hstates + carry
-    # states entering each chunk: shift right
-    h_prev = jnp.concatenate([
-        (init_state[:, None].astype(jnp.float32) if init_state is not None
-         else jnp.zeros_like(hstates[:, :1])),
-        hstates[:, :-1]], axis=1)  # (BH, ncache, N, P)
+        dcum, hstates = jax.lax.associative_scan(
+            comb, (dsums.swapaxes(0, 1), states.swapaxes(0, 1)))
+        hstates = hstates.swapaxes(0, 1)  # (BH, ncache, N, P): chunk ends
+        if init_state is not None:
+            carry = jnp.exp(dcum.swapaxes(0, 1))[..., None, None] * \
+                init_state[:, None].astype(jnp.float32)
+            hstates = hstates + carry
+        # states entering each chunk: shift right
+        h_prev = jnp.concatenate([
+            (init_state[:, None].astype(jnp.float32) if init_state is not None
+             else jnp.zeros_like(hstates[:, :1])),
+            hstates[:, :-1]], axis=1)  # (BH, ncache, N, P)
 
-    # Y_inter[t] = exp(cum_t) * C_t @ H_prev(chunk(t))
-    gamma_start = jnp.exp(cum_in)  # (BH,ncache,L)
-    Cc = C.astype(jnp.float32).reshape(bh, nck, chunk, n)
-    y_inter = jnp.einsum("bcln,bcnp->bclp", Cc, h_prev) * \
-        gamma_start[..., None]
-    y = y_intra + y_inter.reshape(bh, s, p)
-    return y.astype(x.dtype), hstates[:, -1]
+    with jax.named_scope("inter"):
+        # Y_inter[t] = exp(cum_t) * C_t @ H_prev(chunk(t))
+        gamma_start = jnp.exp(cum_in)  # (BH,ncache,L)
+        Cc = C.astype(jnp.float32).reshape(bh, nck, chunk, n)
+        y_inter = jnp.einsum("bcln,bcnp->bclp", Cc, h_prev) * \
+            gamma_start[..., None]
+        y = y_intra + y_inter.reshape(bh, s, p)
+        y = y.astype(x.dtype)
+    with jax.named_scope("carry"):
+        return y, hstates[:, -1]
 
 
 __all__ = ["ssd_chunk_scan"]

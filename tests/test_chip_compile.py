@@ -1,11 +1,14 @@
 """Compile the main path's Pallas kernels for a TPU v5e that is described,
 not attached: Mosaic's refusals (VMEM over budget, unaligned blocks, SMEM
-layouts) show here, where interpret mode never sees them.
+layouts) show here, where interpret mode never sees them.  Each kernel
+keeps its stable name, and the wrappers' step scopes survive the chip's
+compiler, as a profiler trace reads them.
 
 The topology is described inside a fixture: only the worker that runs
 this file loads the TPU compiler, and it skips from there when it cannot.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +65,31 @@ def compile_for_chip(one_chip, no_compile_cache):
     return compile_
 
 
+def kernels(text: str) -> set:
+    """The Pallas kernels (``tpu_custom_call``s) of compiled HLO text, by
+    the instruction name that the kernel's ``name`` gives them and that a
+    profiler trace shows."""
+    return set(re.findall(r'^\s*(?:ROOT\s+)?%([A-Za-z_]\w*?)(?:\.\d+)* = '
+                          r'[^\n]*custom_call_target="tpu_custom_call"',
+                          text, re.M))
+
+
+STEPS = {"pad", "unpad", "repeat", "layout", "decay", "carry", "inter"}
+
+
+def steps(text: str) -> set:
+    """The wrapper steps (``ops.py``'s step scopes) that some op of
+    compiled HLO text carries in its ``op_name``, after a ``covenant_*``
+    part."""
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        parts = op_name.split("/")[:-1]
+        at = [i for i, p in enumerate(parts) if p.startswith("covenant_")]
+        if at:
+            found.update(p for p in parts[at[0] + 1:] if p in STEPS)
+    return found
+
+
 # (m, n, k): BERT-LG GEMM1, qwen3-0.6b FFN in/out and LM head at 4096 tokens
 GEMMS = {"bert_gemm1": (384, 4096, 1024), "qwen3_ffn_in": (4096, 3072, 1024),
          "qwen3_ffn_out": (4096, 1024, 3072),
@@ -78,7 +106,10 @@ def test_covenant_matmul_compiles(compile_for_chip, gemm, dtype):
         lambda a, b: ops.covenant_matmul(a, b, blocks=blocks,
                                          interpret=False),
         ((m, k), dtype), ((k, n), dtype))
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"matmul"}
+    bm, bn, bk = blocks
+    padded = m % bm or n % bn or k % bk
+    assert steps(text) == ({"pad", "unpad"} if padded else set())
 
 
 def test_flash_attention_compiles(compile_for_chip):
@@ -89,7 +120,8 @@ def test_flash_attention_compiles(compile_for_chip):
                                                interpret=False),
         ((1, 16, 2048, 128), bf), ((1, 8, 2048, 128), bf),
         ((1, 8, 2048, 128), bf))
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"flash_attention"}
+    assert steps(text) == {"repeat", "layout"}
 
 
 def test_flash_decode_compiles(compile_for_chip):
@@ -100,7 +132,8 @@ def test_flash_decode_compiles(compile_for_chip):
                                                          interpret=False),
         ((8, 16, 128), bf), ((8, 8, 32768, 128), bf),
         ((8, 8, 32768, 128), bf), ((8,), jnp.int32))
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"flash_decode"}
+    assert steps(text) == {"repeat", "layout"}
 
 
 def test_ssd_chunk_scan_compiles(compile_for_chip):
@@ -112,7 +145,9 @@ def test_ssd_chunk_scan_compiles(compile_for_chip):
                                                 interpret=False),
         ((1, 2048, 80, 64), bf), ((1, 2048, 80), f32), ((80,), f32),
         ((1, 2048, 1, 128), bf), ((1, 2048, 1, 128), bf))
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"ssd_chunk_scan"}
+    # B/C's repeat fuses into their relayout, which names the fusion
+    assert steps(text) == {"layout", "decay", "carry", "inter"}
 
 
 def test_sharded_train_step_keeps_its_shardings(topo, no_compile_cache):
