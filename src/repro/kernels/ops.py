@@ -8,13 +8,14 @@ tiler's Algorithm-1 choice (``tiling.gemm_blocks`` / ``attention_blocks``).
 Each wrapper runs under a named scope of its own name, and each of its
 steps that is no kernel under one step scope, so that every op it makes
 carries ``<wrapper>/<step>`` in its HLO ``op_name`` and a profiler trace
-can attribute it (``ssd_chunk_scan`` adds its own steps):
+can attribute it:
 
 * ``pad``: operands padded to block multiples;
 * ``unpad``: the output sliced back to the caller's extent;
 * ``repeat``: K/V, lengths, B/C or A repeated over heads;
 * ``layout``: reshapes and transposes into and out of the kernels'
-  operand layouts.
+  operand layouts, and ``ssd_chunk_scan``'s dt and dt·A rows (its
+  cumsum, decays and carry across chunks run in the kernel).
 
 The scopes are metadata: they change no op of the compiled program.
 """

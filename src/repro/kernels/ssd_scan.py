@@ -7,11 +7,13 @@ The SSD dual form splits the sequence into chunks of length L:
 * inter-chunk (tiny recurrence):       H_c     = exp(ΔA_c) H_{c-1} + S_c
 * state -> output (GEMM):              Y_inter = γ_start ⊙ (C H_{c-1})
 
-The Pallas kernel fuses the two FLOPs-dominant chunk-local stages (Y_intra
-and S_c) per (batch·head, chunk) grid cell — a direct port of the paper's
+The Pallas kernel runs every stage: it walks each head's chunks in order
+(the grid's inner, sequential axis) and carries H in VMEM, so per
+(batch·head, chunk) grid cell it computes the chunk's cumsum of dt·A and
+its decays, Y = Y_intra + Y_inter and the next H — the paper's
 multi-compute-node schedule (MXU for the GEMMs, VPU for the decay masks)
-onto one VMEM-resident block.  The O(chunks) recurrence and the Y_inter
-GEMM run as jnp ops (they are <2% of FLOPs at L=256).
+on one VMEM-resident block.  The wrapper only lays out dt and dt·A as
+lane-dense rows.
 
 Shapes (head-batched): x (BH, S, P), dt (BH, S), B,C (BH, S, N), A (BH,).
 """
@@ -25,37 +27,52 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_chunk_kernel(x_ref, dt_ref, cum_ref, cum_t_ref, decay_ref, b_ref,
-                      c_ref, y_ref, state_ref):
-    """One (bh, chunk) cell: intra-chunk output + end-of-chunk state.
+def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, has_init):
+    """One (bh, chunk) cell: the chunk's output and the state after it.
 
-    ``cum`` is the chunk's inclusive cumsum of dt*A, given both as a
-    column (L, 1) and as a row (1, L) so that no relayout happens here;
-    ``decay`` is exp(cum_L - cum), the decay of each step to the chunk's
-    end."""
+    ``dt`` and ``a`` = dt·A are rows (1, L).  The state window ``h`` stays
+    resident over the chunk axis: at the first chunk it is set from
+    ``init`` (or zeros), then each chunk reads the state entering it and
+    leaves the state after it, the final state at the last chunk.  Only
+    2-D ops, since Mosaic lowers no 1-D cumsum and no lane slice: the
+    cumsum is a masked lane reduction."""
+    init_ref, y_ref, h_ref = refs if has_init else (None, *refs)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_ref[0] = (init_ref[0].astype(jnp.float32) if has_init
+                    else jnp.zeros(h_ref.shape[1:], jnp.float32))
+
     x = x_ref[0].astype(jnp.float32)      # (L, P)
-    dt = dt_ref[0].astype(jnp.float32)    # (L, 1)
-    cum = cum_ref[0]                      # (L, 1)
-    cum_t = cum_t_ref[0]                  # (1, L)
-    decay_to_end = decay_ref[0]           # (L, 1)
+    dt = dt_ref[0].astype(jnp.float32)    # (1, L)
+    a = a_ref[0]                          # (1, L)
     bmat = b_ref[0].astype(jnp.float32)   # (L, N)
     cmat = c_ref[0].astype(jnp.float32)   # (L, N)
     L = x.shape[0]
-    # Γ[i,j] = exp(cum_i - cum_j) for j <= i (segment decay), else 0.
-    # Mask inside the exp so the masked branch cannot overflow.
     ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    gamma = jnp.exp(jnp.where(jj <= ii, cum - cum_t, -1e30))
+    causal = jj <= ii
+    # inclusive cumsum of dt·A as a column, and the same values as a row,
+    # picked off the diagonal; the chunk's total log-decay
+    cum = jnp.sum(jnp.where(causal, a, 0.0), axis=1, keepdims=True)
+    cum_t = jnp.sum(jnp.where(ii == jj, cum, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(a, axis=1, keepdims=True)                    # (1, 1)
+    # Γ[i,j] = exp(cum_i - cum_j) for j <= i (segment decay), else 0.
+    # Mask inside the exp so the masked branch cannot overflow.
+    gamma = jnp.exp(jnp.where(causal, cum - cum_t, -1e30))
 
-    # Y_intra = ((C B^T) ⊙ Γ) (Δ ⊙ X)
-    att = jnp.dot(cmat, bmat.T, preferred_element_type=jnp.float32) * gamma
-    xdt = x * dt
-    y_ref[0] = jnp.dot(att, xdt, preferred_element_type=jnp.float32
-                       ).astype(y_ref.dtype)
+    # Y = ((C B^T) ⊙ Γ) (Δ ⊙ X) + exp(cum) ⊙ (C H)
+    att = jnp.dot(cmat, bmat.T, preferred_element_type=jnp.float32) * \
+        gamma * dt
+    h = h_ref[0]                          # (N, P): state entering the chunk
+    y = jnp.dot(att, x, preferred_element_type=jnp.float32) + \
+        jnp.exp(cum) * jnp.dot(cmat, h, preferred_element_type=jnp.float32)
+    y_ref[0] = y.astype(y_ref.dtype)
 
-    # S_c = (B ⊙ exp(cum_L - cum))^T (Δ ⊙ X)   -> (N, P)
-    state_ref[0] = jnp.dot((bmat * decay_to_end).T, xdt,
-                           preferred_element_type=jnp.float32)
+    # H <- exp(total) H + S_c,  S_c = (B ⊙ Δ exp(total - cum))^T X
+    w = dt * jnp.exp(total - cum_t)       # (1, L)
+    h_ref[0] = jnp.exp(total) * h + jnp.dot(
+        bmat.T * w, x, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -63,91 +80,48 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                    C: jax.Array, *, chunk: int = 256,
                    init_state: jax.Array | None = None,
                    interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Head-batched SSD: x (BH,S,P), dt (BH,S), A (BH,), B/C (BH,S,N).
+    """Head-batched SSD: x (BH,S,P), dt (BH,S), A (BH,), B/C (BH,S,N),
+    init_state (BH,N,P).
 
-    Returns (y (BH,S,P), final_state (BH,N,P)).  S % chunk == 0 (ops.py
-    pads); on the TPU ``chunk`` is a multiple of 128 or all of S.  The
-    chunk-local heavy stages run in the Pallas kernel; the per-chunk
-    cumsum and the cross-chunk combination are jnp, each stage under a
-    step scope as ``ops.py`` names its wrappers' steps: ``decay`` (the
-    cumsum and its exps), ``layout`` (the kernel's column and row
-    operands), ``carry`` (the recurrence across chunks) and ``inter`` (the
-    output of the states entering each chunk).
+    Returns (y (BH,S,P) in x's dtype, final_state (BH,N,P) f32).
+    S % chunk == 0 (ops.py pads, with dt zero); on the TPU ``chunk`` is a
+    multiple of 128 or all of S.  Everything runs in the Pallas kernel but
+    the ``layout`` step, as ``ops.py`` names its wrappers' steps: dt and
+    dt·A as the kernel's (BH, 1, S) row operands.
     """
     bh, s, p = x.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)
     nck = s // chunk
-    with jax.named_scope("decay"):
-        dtf = dt.astype(jnp.float32).reshape(bh, nck, chunk)
-        cum_in = jnp.cumsum(dtf * A.astype(jnp.float32)[:, None, None],
-                            axis=-1)
-        cum = cum_in.reshape(bh, s)
-        decay = jnp.exp(cum_in[..., -1:] - cum_in).reshape(bh, s, 1)
     with jax.named_scope("layout"):
-        operands = (x, dt[..., None], cum[..., None], cum[:, None, :], decay,
-                    B, C)
+        a = dt.astype(jnp.float32) * A.astype(jnp.float32)[:, None]
+        operands = [x, dt[:, None, :], a[:, None, :], B, C]
 
-    y_intra, states = pl.pallas_call(
-        _ssd_chunk_kernel,
+    def seq(w):
+        return pl.BlockSpec((1, chunk, w), lambda b, c: (b, c, 0))
+
+    row = pl.BlockSpec((1, 1, chunk), lambda b, c: (b, 0, c))
+    state = pl.BlockSpec((1, n, p), lambda b, c: (b, 0, 0))
+    in_specs = [seq(p), row, row, seq(n), seq(n)]
+    if init_state is not None:
+        operands.append(init_state)
+        in_specs.append(state)
+
+    return tuple(pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel,
+                          has_init=init_state is not None),
         name="ssd_chunk_scan",
         grid=(bh, nck),
-        in_specs=[
-            pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, c: (b, 0, c)),
-            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, p), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, n, p), lambda b, c: (b * nck + c, 0, 0)),
-        ],
+        in_specs=in_specs,
+        out_specs=[seq(p), state],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, p), jnp.float32),
-            jax.ShapeDtypeStruct((bh * nck, n, p), jnp.float32),
+            jax.ShapeDtypeStruct((bh, s, p), x.dtype),
+            jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
-
-    with jax.named_scope("carry"):
-        states = states.reshape(bh, nck, n, p)
-        dsums = cum_in[..., -1]               # (BH, nck) chunk log-decay
-
-        # inter-chunk recurrence over ncache states:
-        # H_c = e^{dsum_c} H_{c-1} + S_c
-        def comb(left, right):
-            dl, sl = left
-            dr, sr = right
-            return dl + dr, sr + sl * jnp.exp(dr)[..., None, None]
-
-        dcum, hstates = jax.lax.associative_scan(
-            comb, (dsums.swapaxes(0, 1), states.swapaxes(0, 1)))
-        hstates = hstates.swapaxes(0, 1)  # (BH, ncache, N, P): chunk ends
-        if init_state is not None:
-            carry = jnp.exp(dcum.swapaxes(0, 1))[..., None, None] * \
-                init_state[:, None].astype(jnp.float32)
-            hstates = hstates + carry
-        # states entering each chunk: shift right
-        h_prev = jnp.concatenate([
-            (init_state[:, None].astype(jnp.float32) if init_state is not None
-             else jnp.zeros_like(hstates[:, :1])),
-            hstates[:, :-1]], axis=1)  # (BH, ncache, N, P)
-
-    with jax.named_scope("inter"):
-        # Y_inter[t] = exp(cum_t) * C_t @ H_prev(chunk(t))
-        gamma_start = jnp.exp(cum_in)  # (BH,ncache,L)
-        Cc = C.astype(jnp.float32).reshape(bh, nck, chunk, n)
-        y_inter = jnp.einsum("bcln,bcnp->bclp", Cc, h_prev) * \
-            gamma_start[..., None]
-        y = y_intra + y_inter.reshape(bh, s, p)
-        y = y.astype(x.dtype)
-    with jax.named_scope("carry"):
-        return y, hstates[:, -1]
+    )(*operands))
 
 
 __all__ = ["ssd_chunk_scan"]

@@ -74,7 +74,7 @@ def kernels(text: str) -> set:
                           text, re.M))
 
 
-STEPS = {"pad", "unpad", "repeat", "layout", "decay", "carry", "inter"}
+STEPS = {"pad", "unpad", "repeat", "layout"}
 
 
 def steps(text: str) -> set:
@@ -147,7 +147,27 @@ def test_ssd_chunk_scan_compiles(compile_for_chip):
         ((1, 2048, 1, 128), bf), ((1, 2048, 1, 128), bf))
     assert kernels(text) == {"ssd_chunk_scan"}
     # B/C's repeat fuses into their relayout, which names the fusion
-    assert steps(text) == {"layout", "decay", "carry", "inter"}
+    assert steps(text) == {"layout"}
+    # the cumsum, decays, carry across chunks and the states' read-out
+    # run in the kernel: no op outside it names them
+    outside = [name for name in re.findall(r'op_name="([^"]*)"', text)
+               if {"decay", "carry", "inter"} & set(name.split("/"))]
+    assert not outside, outside
+
+
+@pytest.mark.parametrize("s", [2048, 128])
+def test_ssd_chunk_scan_compiles_with_init_state(compile_for_chip, s):
+    """Continued from a state, over 8 chunks and over one of 128 tokens:
+    the kernel's state input and its single-chunk grid."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    text = compile_for_chip(
+        lambda x, dt, a, b, c, st: ops.covenant_ssd(
+            x, dt, a, b, c, chunk=256, init_state=st, return_state=True,
+            interpret=False),
+        ((1, s, 80, 64), bf), ((1, s, 80), f32), ((80,), f32),
+        ((1, s, 1, 128), bf), ((1, s, 1, 128), bf), ((1, 80, 64, 128), f32))
+    assert kernels(text) == {"ssd_chunk_scan"}
+    assert steps(text) == {"layout"}
 
 
 def test_sharded_train_step_keeps_its_shardings(topo, no_compile_cache):

@@ -16,7 +16,7 @@ import pytest
 
 from repro.kernels import ops
 
-STEPS = ("pad", "unpad", "repeat", "layout", "decay", "carry", "inter")
+STEPS = ("pad", "unpad", "repeat", "layout")
 # harness scopes of the on-chip benchmark; a step name must not start with
 # one, or its reduction would read the step as the harness's scope
 HARNESS_SCOPES = ("gemm", "attn", "decode", "ssd", "kv_write", "state",
@@ -57,9 +57,7 @@ CASES = {
         [S((1, 40, 4, 8), BF), S((1, 40, 4), F32), S((4,), F32),
          S((1, 40, 1, 16), BF), S((1, 40, 1, 16), BF), S((1, 4, 8, 16), F32)],
         {("pad", "pad"), ("layout", "transpose"), ("repeat", "broadcast"),
-         ("unpad", "slice"), ("decay", "reduce-window"),
-         ("decay", "exponential"), ("carry", "concatenate"),
-         ("inter", "dot")}),
+         ("unpad", "slice")}),
 }
 
 

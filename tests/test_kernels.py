@@ -149,6 +149,12 @@ SSD_CASES = [
     dict(b=1, s=100, h=4, p=8, g=4, n=16, chunk=32),
     dict(b=2, s=33, h=2, p=8, g=1, n=4, chunk=16),
     dict(b=1, s=16, h=2, p=4, g=2, n=4, chunk=16),  # single chunk
+    # 16 chunks under strong decay: the in-kernel cumsum and serial carry
+    dict(b=1, s=256, h=2, p=8, g=1, n=8, chunk=16, a_max=8.0),
+    # 9 chunks, the last one padded, four heads to a group
+    dict(b=2, s=130, h=4, p=8, g=1, n=8, chunk=16, a_max=8.0),
+    # two heads to a group, no final state asked for
+    dict(b=2, s=48, h=8, p=8, g=4, n=8, chunk=16, state=False),
 ]
 
 
@@ -157,14 +163,18 @@ def test_ssd_matches_sequential_ref(case):
     b, s, h, p, g, n = (case[k] for k in "bshpgn")
     x = randn(b, s, h, p)
     dt = jnp.asarray(rng.uniform(0.01, 0.2, (b, s, h)), jnp.float32)
-    A = -jnp.asarray(rng.uniform(0.5, 2.0, (h,)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(0.5, case.get("a_max", 2.0), (h,)),
+                     jnp.float32)
     B = randn(b, s, g, n)
     C = randn(b, s, g, n)
-    got, st = ops.covenant_ssd(x, dt, A, B, C, chunk=case["chunk"],
-                               return_state=True, interpret=True)
+    state = case.get("state", True)
+    got = ops.covenant_ssd(x, dt, A, B, C, chunk=case["chunk"],
+                           return_state=state, interpret=True)
     want, wst = ref.ssd_ref(x, dt, A, B, C, return_state=True)
+    if state:
+        got, st = got
+        np.testing.assert_allclose(st, wst, atol=2e-3)
     np.testing.assert_allclose(got, want, atol=2e-3)
-    np.testing.assert_allclose(st, wst, atol=2e-3)
 
 
 def test_ssd_init_state_continuation():
@@ -185,6 +195,27 @@ def test_ssd_init_state_continuation():
                                return_state=True, interpret=True)
     np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), y_full, atol=2e-3)
     np.testing.assert_allclose(st2, st_full, atol=2e-3)
+
+
+@pytest.mark.parametrize("split", [24, 40, 56])
+def test_ssd_init_state_continuation_off_chunk(split):
+    """A split off the chunk grid: the second call's chunks (and, at 56,
+    its single chunk of 8) start where the whole call's do not."""
+    b, s, h, p, g, n = 1, 64, 4, 8, 2, 8
+    x = randn(b, s, h, p)
+    dt = jnp.asarray(rng.uniform(0.01, 0.2, (b, s, h)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(0.5, 2.0, (h,)), jnp.float32)
+    B, C = randn(b, s, g, n), randn(b, s, g, n)
+    want, wst = ref.ssd_ref(x, dt, A, B, C, return_state=True)
+    y1, st1 = ops.covenant_ssd(x[:, :split], dt[:, :split], A,
+                               B[:, :split], C[:, :split], chunk=16,
+                               return_state=True, interpret=True)
+    y2, st2 = ops.covenant_ssd(x[:, split:], dt[:, split:], A,
+                               B[:, split:], C[:, split:], chunk=16,
+                               init_state=st1, return_state=True,
+                               interpret=True)
+    np.testing.assert_allclose(jnp.concatenate([y1, y2], 1), want, atol=2e-3)
+    np.testing.assert_allclose(st2, wst, atol=2e-3)
 
 
 def test_ssd_decay_reduces_state_influence():
