@@ -4,10 +4,12 @@
 only when asked for); ``ref`` holds the pure-jnp oracles every kernel is tested
 against; ``tiling`` is the Algorithm-1 -> BlockSpec bridge.
 """
-from . import flash_attention, matmul, ops, ref, ssd_scan, tiling
+from . import (flash_attention, grouped_matmul, matmul, ops, ref, ssd_scan,
+               tiling)
 from .ops import (covenant_attention, covenant_decode_attention,
-                  covenant_matmul, covenant_ssd)
+                  covenant_experts, covenant_matmul, covenant_ssd)
 
 __all__ = ["covenant_attention", "covenant_decode_attention",
-           "covenant_matmul", "covenant_ssd", "flash_attention", "matmul",
-           "ops", "ref", "ssd_scan", "tiling"]
+           "covenant_experts", "covenant_matmul", "covenant_ssd",
+           "flash_attention", "grouped_matmul", "matmul", "ops", "ref",
+           "ssd_scan", "tiling"]

@@ -14,9 +14,16 @@ can attribute it:
 * ``unpad``: the output sliced back to the caller's extent;
 * ``repeat``: K/V, lengths, B/C or A repeated over heads;
 * ``layout``: reshapes and transposes into and out of the kernels'
-  operand layouts, and ``ssd_chunk_scan``'s dt and dt·A rows (its
-  cumsum, decays and carry across chunks run in the kernel).
+  operand layouts, ``ssd_chunk_scan``'s dt and dt·A rows (its cumsum,
+  decays and carry across chunks run in the kernel), and the expert
+  layer's sort of (token, expert) pairs into groups, the gather of their
+  rows and the group offsets;
+* ``route``: the expert layer's router GEMM, top-k and gates;
+* ``swiglu``: the expert layer's SiLU(gate) * up between its two GEMMs;
+* ``combine``: the expert layer's gated sum of its rows back to tokens.
 
+The wrappers are ``covenant_matmul``, ``covenant_attention``,
+``covenant_decode_attention``, ``covenant_ssd`` and ``covenant_experts``.
 The scopes are metadata: they change no op of the compiled program.
 """
 from __future__ import annotations
@@ -26,9 +33,10 @@ import jax.numpy as jnp
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _fa, flash_decode as _fd
+from .grouped_matmul import grouped_matmul as _gmm
 from .matmul import matmul as _mm
 from .ssd_scan import ssd_chunk_scan as _ssd
-from .tiling import SUBLANE, attention_blocks, gemm_blocks
+from .tiling import SUBLANE, attention_blocks, gemm_blocks, grouped_gemm_blocks
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -167,10 +175,98 @@ def covenant_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         return y
 
 
+def expert_routing(x: jax.Array, router_w: jax.Array,
+                   top_k: int) -> tuple[jax.Array, jax.Array]:
+    """Granite's (GraniteMoeHybrid's) routing of the tokens x (T, d) over
+    all experts: the f32 router logits x @ router_w, their top_k, and a
+    softmax over those k logits.  Returns the experts (T, top_k) int32 and
+    their gates (T, top_k) f32, in order of falling logit."""
+    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+    top, experts = jax.lax.top_k(logits, top_k)
+    return experts, jax.nn.softmax(top, axis=-1)
+
+
+@jax.named_scope("covenant_experts")
+def covenant_experts(x: jax.Array, router_w: jax.Array, w_in: jax.Array,
+                     w_out: jax.Array, *, top_k: int, first: int,
+                     n_experts: int, interpret: bool = False) -> jax.Array:
+    """The part of a dropless top-k mixture of SwiGLU experts that the
+    experts ``first .. first + held - 1`` give, for an expert layer that
+    holds those ``held`` of the ``n_experts``.
+
+    x (T, d) bf16; router_w (d, n_experts), over all experts; w_in (held,
+    d, 2f), the gate half first (Granite's ``input_linear``); w_out (held,
+    f, d).  Every token is routed over all experts (``expert_routing``);
+    each (token, expert) pair whose expert is held here is computed, none
+    dropped, and the rest are left to the layers that hold their experts.
+    Returns the gated sum of this layer's expert outputs per token, (T, d)
+    f32.  The pairs are sorted by expert into groups padded to the
+    grouped GEMM's block m, which ``grouped_matmul`` runs twice, with
+    SwiGLU between."""
+    t, d = x.shape
+    held, _, f2 = w_in.shape
+    f = f2 // 2
+    pairs = t * top_k
+    with jax.named_scope("route"):
+        experts, gates = expert_routing(x, router_w, top_k)
+    # blocks for groups of the mean rows an expert gets; both GEMMs share
+    # the first one's block m, which the rows are padded to
+    rows = -(-pairs // n_experts)
+    bm, bn1, bk1 = grouped_gemm_blocks(rows, f2, d)
+    _, bn2, bk2 = grouped_gemm_blocks(rows, d, f)
+    # the most row blocks the pairs can fill: every held expert that gets
+    # a pair adds at most bm - 1 rows of padding
+    n_rows = (pairs + min(held, pairs) * (bm - 1)) // bm * bm
+    with jax.named_scope("layout"):
+        local = experts.reshape(-1) - first                    # (pairs,)
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)      # pairs not held sort last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held]
+        starts = jnp.cumsum(sizes) - sizes
+        padded = -(-sizes // bm) * bm
+        offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                   jnp.cumsum(padded).astype(jnp.int32)])
+        g_sorted = group[order]
+        g_safe = jnp.minimum(g_sorted, held - 1)
+        row_sorted = jnp.where(
+            g_sorted < held,
+            offsets[g_safe] + jnp.arange(pairs) - starts[g_safe], n_rows)
+        row = jnp.zeros(pairs, jnp.int32).at[order].set(row_sorted)
+        # the token of each row of the padded layout; t (a zero row) for
+        # the rows that pad a group
+        token = jnp.full(n_rows, t, jnp.int32).at[row_sorted].set(
+            order // top_k, mode="drop")
+    with jax.named_scope("pad"):
+        xz = jnp.pad(x, ((0, 1), (0, 0)))
+        w_in = _pad_to(_pad_to(w_in, 1, bk1), 2, bn1)
+        w_out = _pad_to(_pad_to(w_out, 1, bk2), 2, bn2)
+    with jax.named_scope("layout"):
+        xs = xz[token]
+    with jax.named_scope("pad"):
+        xs = _pad_to(xs, 1, bk1)
+    h = _gmm(xs, w_in, offsets, block_m=bm, block_n=bn1, block_k=bk1,
+             interpret=interpret)
+    with jax.named_scope("swiglu"):
+        a = (jax.nn.silu(h[:, :f]) * h[:, f:f2]).astype(x.dtype)
+    with jax.named_scope("pad"):
+        a = _pad_to(a, 1, bk2)
+    y = _gmm(a, w_out, offsets, block_m=bm, block_n=bn2, block_k=bk2,
+             interpret=interpret)
+    with jax.named_scope("unpad"):
+        y = y[:, :d]
+    with jax.named_scope("combine"):
+        w = jnp.where(mine, gates.reshape(-1), 0.0)
+        yp = jnp.where(mine[:, None], y[jnp.minimum(row, n_rows - 1)], 0.0)
+        return (w[:, None] * yp).reshape(t, top_k, d).sum(axis=1)
+
+
 # re-export oracles for convenience
 matmul_ref = _ref.matmul_ref
 attention_ref = _ref.attention_ref
 ssd_ref = _ref.ssd_ref
+experts_ref = _ref.experts_ref
 
 __all__ = ["attention_ref", "covenant_attention", "covenant_decode_attention",
-           "covenant_matmul", "covenant_ssd", "matmul_ref", "ssd_ref"]
+           "covenant_experts", "covenant_matmul", "covenant_ssd",
+           "expert_routing", "experts_ref", "matmul_ref", "ssd_ref"]

@@ -101,4 +101,31 @@ def ssd_ref(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     return y
 
 
-__all__ = ["attention_ref", "matmul_ref", "ssd_ref"]
+def experts_ref(x: jax.Array, router_w: jax.Array, w_in: jax.Array,
+                w_out: jax.Array, *, top_k: int, first: int,
+                n_experts: int) -> jax.Array:
+    """Oracle of ``ops.covenant_experts``: the part of a top-k mixture of
+    SwiGLU experts that the held experts ``first .. first + held - 1`` of
+    ``n_experts`` give, in f32 at ``highest`` precision, one expert at a
+    time over every token.  Routing as GraniteMoeHybrid's: the top_k of
+    the router logits over all experts, then a softmax over those k.
+    x (T, d), router_w (d, n_experts), w_in (held, d, 2f) gate half first,
+    w_out (held, f, d) -> (T, d) f32."""
+    f32 = jnp.float32
+    t = x.shape[0]
+    held, _, f2 = w_in.shape
+    f = f2 // 2
+    with jax.default_matmul_precision("highest"):
+        x = x.astype(f32)
+        top, experts = jax.lax.top_k(x @ router_w.astype(f32), top_k)
+        gates = jnp.zeros((t, n_experts), f32).at[
+            jnp.arange(t)[:, None], experts].set(jax.nn.softmax(top, -1))
+        out = jnp.zeros((t, w_out.shape[2]), f32)
+        for e in range(held):
+            h = x @ w_in[e].astype(f32)
+            a = jax.nn.silu(h[:, :f]) * h[:, f:]
+            out = out + gates[:, first + e, None] * (a @ w_out[e].astype(f32))
+    return out
+
+
+__all__ = ["attention_ref", "experts_ref", "matmul_ref", "ssd_ref"]

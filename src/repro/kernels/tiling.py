@@ -21,6 +21,8 @@ from repro.core.scheduler import enumerate_tilings, plan_operands
 # MXU systolic dims / VPU lane layout on TPU v5e
 MXU = 128
 SUBLANE = 8
+# rows of one (sublane, lane) tile of a bf16 array: two values a sublane
+BF16_SUBLANE = 16
 
 
 def _align_score(t: dict[str, int], dims: dict[str, int]) -> tuple:
@@ -66,6 +68,16 @@ def gemm_blocks(m: int, n: int, k: int, in_dtype: str = "bf16",
     return bm, bn, bk
 
 
+def grouped_gemm_blocks(rows: int, n: int, k: int) -> tuple[int, int, int]:
+    """(block_m, block_n, block_k) for a grouped bf16 GEMM (``grouped_matmul``)
+    whose groups hold about ``rows`` rows each: the tiler's choice for one
+    group's (rows, n, k) GEMM, rows first rounded up to a whole bf16 tile
+    of rows.  block_m is a multiple of that tile, so that padding each group
+    to block_m keeps every row block aligned."""
+    bm, bn, bk = gemm_blocks(_round_up(rows, BF16_SUBLANE), n, k)
+    return _round_up(bm, BF16_SUBLANE), bn, bk
+
+
 def _round_up(x: int, unit: int) -> int:
     return max(unit, math.ceil(x / unit) * unit)
 
@@ -86,4 +98,5 @@ def attention_blocks(seq_q: int, seq_k: int, head_dim: int,
     return bq, bkv
 
 
-__all__ = ["MXU", "SUBLANE", "attention_blocks", "gemm_blocks"]
+__all__ = ["BF16_SUBLANE", "MXU", "SUBLANE", "attention_blocks", "gemm_blocks",
+           "grouped_gemm_blocks"]

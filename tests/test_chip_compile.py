@@ -74,7 +74,7 @@ def kernels(text: str) -> set:
                           text, re.M))
 
 
-STEPS = {"pad", "unpad", "repeat", "layout"}
+STEPS = {"pad", "unpad", "repeat", "layout", "route", "swiglu", "combine"}
 
 
 def steps(text: str) -> set:
@@ -168,6 +168,26 @@ def test_ssd_chunk_scan_compiles_with_init_state(compile_for_chip, s):
         ((1, s, 1, 128), bf), ((1, s, 1, 128), bf), ((1, 80, 64, 128), f32))
     assert kernels(text) == {"ssd_chunk_scan"}
     assert steps(text) == {"layout"}
+
+
+def test_covenant_experts_compiles(compile_for_chip):
+    """granite-4.0-h-small's expert layer on one of two chips: 32 tokens of
+    4096 routed top-10 over 72 experts, 36 held, each a SwiGLU of 768.
+    Both GEMMs are one grouped kernel each, and no op but the kernels
+    touches the held experts' weights: the widths need no pad (``pad`` is
+    the zero row that padding rows of a group read)."""
+    bf = jnp.bfloat16
+    text = compile_for_chip(
+        lambda x, r, wi, wo: ops.covenant_experts(
+            x, r, wi, wo, top_k=10, first=0, n_experts=72, interpret=False),
+        ((32, 4096), bf), ((4096, 72), bf), ((36, 4096, 1536), bf),
+        ((36, 768, 4096), bf))
+    assert kernels(text) == {"grouped_matmul"}
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
+    assert steps(text) == {"route", "layout", "pad", "swiglu", "combine"}
+    weights = re.findall(r"= bf16\[36,(?:4096,1536|768,4096)\]\S* ([\w-]+)\(",
+                         text)
+    assert set(weights) == {"parameter"}, weights
 
 
 def test_sharded_train_step_keeps_its_shardings(topo, no_compile_cache):

@@ -6,7 +6,8 @@ device time to the wrapper's pads, repeats and relayouts.
 Each wrapper is compiled on the CPU (Pallas interpreter) at shapes that
 need every step: a GEMM padded on m, n and k; GQA 8/2 with query and K/V
 lengths no block multiple; a decode cache no block multiple; an SSD with
-one group of four heads, a sequence no chunk multiple and an initial state.
+one group of four heads, a sequence no chunk multiple and an initial state;
+an expert layer holding 3 of 8 experts at widths no block multiple.
 """
 import re
 
@@ -16,7 +17,7 @@ import pytest
 
 from repro.kernels import ops
 
-STEPS = ("pad", "unpad", "repeat", "layout")
+STEPS = ("pad", "unpad", "repeat", "layout", "route", "swiglu", "combine")
 # harness scopes of the on-chip benchmark; a step name must not start with
 # one, or its reduction would read the step as the harness's scope
 HARNESS_SCOPES = ("gemm", "attn", "decode", "ssd", "kv_write", "state",
@@ -58,15 +59,24 @@ CASES = {
          S((1, 40, 1, 16), BF), S((1, 40, 1, 16), BF), S((1, 4, 8, 16), F32)],
         {("pad", "pad"), ("layout", "transpose"), ("repeat", "broadcast"),
          ("unpad", "slice")}),
+    "covenant_experts": (
+        "grouped_matmul",
+        lambda x, r, wi, wo: ops.covenant_experts(
+            x, r, wi, wo, top_k=2, first=2, n_experts=8, interpret=True),
+        [S((12, 200), BF), S((200, 8), BF), S((3, 200, 260), BF),
+         S((3, 130, 200), BF)],
+        {("route", "dot"), ("layout", "sort"), ("pad", "pad"),
+         ("swiglu", "multiply"), ("unpad", "slice"), ("combine", "reduce")}),
 }
 
 
 def wrapper_steps(text: str, fn: str, kernel: str) -> list:
     """(opcode, steps, op_name) of each op the wrapper ``fn`` made outside
     its kernel: ``steps`` are the step names between ``fn`` and the op's
-    own name in its name stack, jit parts left out.  An op named by the
-    kernel's call alone, or by the kernel's own scope (as the interpreter
-    names the kernel body), is the kernel's."""
+    own name in its name stack, jit parts left out.  An op of a jitted
+    helper (``jit(silu)``) has no own name: its stack ends in the jit part.
+    An op named by the kernel's call alone, or by the kernel's own scope
+    (as the interpreter names the kernel body), is the kernel's."""
     out = []
     for opcode, op_name in INSTR.findall(text):
         parts = op_name.split("/")
@@ -76,7 +86,9 @@ def wrapper_steps(text: str, fn: str, kernel: str) -> list:
                  if not p.startswith("jit(")]
         if not after or after[0] == kernel:
             continue
-        out.append((opcode, [p for p in after[:-1] if p in STEPS], op_name))
+        if not parts[-1].startswith("jit("):
+            after = after[:-1]
+        out.append((opcode, [p for p in after if p in STEPS], op_name))
     return out
 
 

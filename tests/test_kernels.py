@@ -281,3 +281,104 @@ def test_flash_fwd_lse_matches_plain_forward():
                                       interpret=True)
     np.testing.assert_allclose(o1, o2, atol=1e-5)
     assert lse.shape == (bh, s, 1)
+
+
+# ---------------------------------------------------------------------------
+# grouped GEMM and the expert layer
+# ---------------------------------------------------------------------------
+
+
+def test_grouped_gemm_blocks_are_the_tilers_at_whole_bf16_tiles():
+    from repro.kernels.tiling import BF16_SUBLANE, grouped_gemm_blocks
+
+    for rows, n, k in [(5, 1536, 4096), (5, 4096, 768), (569, 1536, 4096)]:
+        bm, bn, bk = grouped_gemm_blocks(rows, n, k)
+        assert bm % BF16_SUBLANE == 0
+        want = gemm_blocks(-(-rows // BF16_SUBLANE) * BF16_SUBLANE, n, k)
+        assert (bn, bk) == want[1:] and bm >= want[0]
+
+
+# group sizes: uneven, an empty group, groups longer than one block of 16
+# rows, and an empty last group
+@pytest.mark.parametrize("sizes", [(3, 0, 40, 16), (0, 1, 17, 0), (33,)])
+def test_grouped_matmul_matches_per_group_dot(sizes):
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    r = np.random.default_rng(11)
+    bm, k, n = 16, 256, 384
+    padded = [-(-s // bm) * bm for s in sizes]
+    offsets = np.concatenate([[0], np.cumsum(padded)]).astype(np.int32)
+    rows = int(offsets[-1]) + 2 * bm          # room for unused row blocks
+    x = np.zeros((rows, k), np.float32)
+    for g, s in enumerate(sizes):
+        x[offsets[g]:offsets[g] + s] = r.standard_normal((s, k))
+    x = jnp.asarray(x, jnp.bfloat16)
+    w = jnp.asarray(r.standard_normal((len(sizes), k, n)), jnp.bfloat16)
+    got = grouped_matmul(x, w, jnp.asarray(offsets), block_m=bm,
+                         block_n=128, block_k=128, interpret=True)
+    for g, s in enumerate(sizes):
+        lo, hi = offsets[g], offsets[g + 1]
+        want = jnp.dot(x[lo:hi], w[g], preferred_element_type=jnp.float32)
+        np.testing.assert_allclose(np.asarray(got[lo:hi]), np.asarray(want),
+                                   rtol=1e-5, atol=1e-3)
+
+
+def moe_layer(seed, t=24, d=256, f=128, experts=16):
+    """Seeded bf16 tokens and weights of a small expert layer, scaled so
+    that each GEMM's output has unit variance."""
+    r = np.random.default_rng(seed)
+
+    def w(shape, fan_in):
+        return jnp.asarray(r.standard_normal(shape) / np.sqrt(fan_in),
+                           jnp.bfloat16)
+    return (jnp.asarray(r.standard_normal((t, d)), jnp.bfloat16),
+            w((d, experts), d), w((experts, d, 2 * f), d),
+            w((experts, f, d), f))
+
+
+@pytest.mark.parametrize("first,held", [(0, 16), (4, 8), (15, 1)])
+def test_covenant_experts_matches_ref(first, held):
+    x, router, w_in, w_out = moe_layer(3)
+    part = dict(top_k=4, first=first, n_experts=16)
+    got = ops.covenant_experts(x, router, w_in[first:first + held],
+                               w_out[first:first + held], **part,
+                               interpret=True)
+    want = ref.experts_ref(x, router, w_in[first:first + held],
+                           w_out[first:first + held], **part)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    # the SwiGLU activation is rounded to bf16 between the two GEMMs
+    err = jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))
+    assert float(err) < 1e-2, float(err)
+
+
+def test_expert_shares_add_up_to_the_whole_layer():
+    """Two chips holding experts 0-5 and 6-15 give parts that, with the
+    shared expert counted once, add up to the uncut layer."""
+    x, router, w_in, w_out = moe_layer(5)
+    r = np.random.default_rng(6)
+    s_in = jnp.asarray(r.standard_normal((256, 512)) / 16, jnp.bfloat16)
+    s_out = jnp.asarray(r.standard_normal((256, 256)) / 16, jnp.bfloat16)
+
+    def shared(x):
+        h = ops.covenant_matmul(x, s_in, interpret=True)
+        a = (jax.nn.silu(h[:, :256]) * h[:, 256:]).astype(jnp.bfloat16)
+        return ops.covenant_matmul(a, s_out, interpret=True)
+
+    parts = [ops.covenant_experts(x, router, w_in[lo:hi], w_out[lo:hi],
+                                  top_k=4, first=lo, n_experts=16,
+                                  interpret=True)
+             for lo, hi in [(0, 6), (6, 16)]]
+    got = parts[0] + parts[1] + shared(x)
+    want = ref.experts_ref(x, router, w_in, w_out, top_k=4, first=0,
+                           n_experts=16) + shared(x)
+    err = jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))
+    assert float(err) < 1e-2, float(err)
+
+
+def test_expert_routing_renormalises_over_the_top_k():
+    x, router, _, _ = moe_layer(7)
+    experts, gates = ops.expert_routing(x, router, 4)
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32))
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 1.0, rtol=1e-6)
+    want = jax.lax.top_k(logits, 4)[1]
+    np.testing.assert_array_equal(np.sort(experts, -1), np.sort(want, -1))
