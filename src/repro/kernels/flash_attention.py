@@ -2,7 +2,8 @@
 
 Online-softmax over kv blocks with running (max, sum) scratch in VMEM.
 Supports causal masking, sliding windows (gemma3's 5:1 local layers) and a
-single-query decode variant whose kv-block grid is combined via LSE.
+single-query decode variant, whose online softmax walks the kv blocks of a
+cache read row-major or with its slots on the lanes.
 
 Block geometry again comes from the Covenant tiler
 (``tiling.attention_blocks``): the QK^T GEMM's Algorithm-1 tiling is the
@@ -112,7 +113,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, scale: float, block_kv: int):
+                   acc_ref, *, scale: float, block_kv: int,
+                   slots_minor: bool):
+    """One (kv head, kv block) step of the online softmax.  K/V blocks are
+    (bkv, d) row-major, or (d, bkv) with the slots on the lanes when
+    ``slots_minor``: only the two contractions differ."""
     b, kj = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kj == 0)
@@ -122,8 +127,9 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)           # (Hg, d) — grouped q heads
-    k = k_ref[0].astype(jnp.float32)           # (bkv, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    k = k_ref[0].astype(jnp.float32)
+    s = jnp.dot(q, k if slots_minor else k.T,
+                preferred_element_type=jnp.float32) * scale    # (Hg, bkv)
     kpos = kj * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = kpos < len_ref[b]
     s = jnp.where(mask, s, NEG_INF)
@@ -133,8 +139,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    # p (Hg, bkv) against v over the slots: v's axis 0, or its lanes
+    pv = jax.lax.dot_general(p, v, (((1,), (1 if slots_minor else 0,)),
+                                    ((), ())),
+                             preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
 
     @pl.when(kj == pl.num_programs(1) - 1)
@@ -144,32 +154,43 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
         o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_kv", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block_kv",
+                                             "slots_minor", "interpret"))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  kv_len: jax.Array, *, scale: float | None = None,
-                 block_kv: int = 512, interpret: bool = False) -> jax.Array:
+                 block_kv: int = 512, slots_minor: bool = False,
+                 interpret: bool = False) -> jax.Array:
     """Single-token decode attention against a KV cache.
 
     q: (BKV, Hg, D) — one query block per kv head (Hg = q heads per kv
-    head); k, v: (BKV, S, D); kv_len: (BKV,) valid lengths.
+    head); k, v: (BKV, S, D), or (BKV, D, S) when ``slots_minor``;
+    kv_len: (BKV,) valid lengths.
     """
     bkv, hg, d = q.shape
-    _, s, _ = k.shape
+    at = 2 if slots_minor else 1           # the slots' axis of k and v
+    s = k.shape[at]
     scale = scale if scale is not None else (d ** -0.5)
     s_pad = -(-s // block_kv) * block_kv
     if s_pad != s:
+        pad = [(0, 0)] * 3
+        pad[at] = (0, s_pad - s)
         with jax.named_scope("pad"):
-            k = jnp.pad(k, [(0, 0), (0, s_pad - s), (0, 0)])
-            v = jnp.pad(v, [(0, 0), (0, s_pad - s), (0, 0)])
-    kernel = functools.partial(_decode_kernel, scale=scale, block_kv=block_kv)
+            k = jnp.pad(k, pad)
+            v = jnp.pad(v, pad)
+    if slots_minor:
+        kv_spec = pl.BlockSpec((1, d, block_kv), lambda b, j: (b, 0, j))
+    else:
+        kv_spec = pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0))
+    kernel = functools.partial(_decode_kernel, scale=scale, block_kv=block_kv,
+                               slots_minor=slots_minor)
     return pl.pallas_call(
         kernel,
         name="flash_decode",
         grid=(bkv, s_pad // block_kv),
         in_specs=[
             pl.BlockSpec((1, hg, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
+            kv_spec,
+            kv_spec,
             # all (BKV,) lengths sit in SMEM; the kernel indexes its row
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],

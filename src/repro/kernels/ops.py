@@ -14,8 +14,9 @@ can attribute it:
 * ``unpad``: the output sliced back to the caller's extent;
 * ``repeat``: K/V, lengths, B/C or A repeated over heads;
 * ``layout``: reshapes and transposes into and out of the kernels'
-  operand layouts, ``ssd_chunk_scan``'s dt and dt·A rows (its cumsum,
-  decays and carry across chunks run in the kernel), and the expert
+  operand layouts, the slots-minor layout a decode cache is held to,
+  ``ssd_chunk_scan``'s dt and dt·A rows (its cumsum, decays and carry
+  across chunks run in the kernel), and the expert
   layer's sort of (token, expert) pairs into groups, the gather of their
   rows and the group offsets;
 * ``route``: the expert layer's router GEMM, top-k and gates;
@@ -30,6 +31,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _fa, flash_decode as _fd
@@ -122,18 +124,34 @@ def covenant_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               block_kv: int = 512,
                               interpret: bool = False) -> jax.Array:
     """One-token GQA decode.  q: (B,Hq,D), cache k/v: (B,Hkv,S,D),
-    kv_len: (B,).  Returns (B,Hq,D)."""
+    kv_len: (B,).  Returns (B,Hq,D).
+
+    Where D is no multiple of 128 and S is, the TPU holds the cache with
+    its slots on the lanes (its compact layout, ``{2,3,1,0}``), and the
+    kernel reads K/V as (B·Hkv, D, S), which is that layout as it lies: a
+    row-major (B·Hkv, S, D) operand would cost a copy of the whole cache,
+    padded from D to 256 lanes.  The cache is held to that layout here, so
+    that a cache just written in another layout (a row scatter's) is
+    copied back once, for the kernel and the caller alike."""
     b, hq, d = q.shape
     _, hkv, s, _ = k.shape
     g = hq // hkv
+    slots_minor = d % 128 != 0 and s % 128 == 0
     with jax.named_scope("layout"):
         qg = q.reshape(b * hkv, g, d)
-        kf = k.reshape(b * hkv, s, d)
-        vf = v.reshape(b * hkv, s, d)
+        if slots_minor:
+            held = Layout(major_to_minor=(0, 1, 3, 2))
+            kf = jnp.swapaxes(with_layout_constraint(k, held), 2, 3)
+            vf = jnp.swapaxes(with_layout_constraint(v, held), 2, 3)
+            kf = kf.reshape(b * hkv, d, s)
+            vf = vf.reshape(b * hkv, d, s)
+        else:
+            kf = k.reshape(b * hkv, s, d)
+            vf = v.reshape(b * hkv, s, d)
     with jax.named_scope("repeat"):
         lens = jnp.repeat(kv_len, hkv)
     out = _fd(qg, kf, vf, lens, scale=scale, block_kv=min(block_kv, s),
-              interpret=interpret)
+              slots_minor=slots_minor, interpret=interpret)
     with jax.named_scope("layout"):
         return out.reshape(b, hq, d)
 

@@ -7,6 +7,7 @@ compiler, as a profiler trace reads them.
 The topology is described inside a fixture: only the worker that runs
 this file loads the TPU compiler, and it skips from there when it cannot.
 """
+import math
 import os
 import re
 
@@ -134,6 +135,70 @@ def test_flash_decode_compiles(compile_for_chip):
         ((8, 8, 32768, 128), bf), ((8,), jnp.int32))
     assert kernels(text) == {"flash_decode"}
     assert steps(text) == {"repeat", "layout"}
+
+
+# the operand the decode kernel reads each cache as, by head dim, at the
+# stablelm-12b decode cell's shapes (granite-4.0-h-small's head dim is 128)
+HELD_AS = {160: "bf16[256,160,8192]{2,1,0}", 128: "bf16[256,8192,128]{2,1,0}"}
+
+
+def copies(text: str, elems: int) -> list:
+    """The shapes of the ``copy`` ops of compiled HLO text that have
+    ``elems`` elements."""
+    return [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+            if math.prod(map(int, dims.split(","))) == elems]
+
+
+@pytest.mark.parametrize("d", list(HELD_AS))
+def test_flash_decode_reads_the_cache_as_held(compile_for_chip, d):
+    """32 sequences of 32 q and 8 KV heads against 8192-slot caches: a
+    head dim of 160 is held slots-minor, and the kernel reads it so; one
+    of 128 is held row-major and read row-major.  Neither copies the
+    cache."""
+    bf = jnp.bfloat16
+    cache = (32, 8, 8192, d)
+    text = compile_for_chip(
+        lambda q, k, v, n: ops.covenant_decode_attention(q, k, v, n,
+                                                         interpret=False),
+        ((32, 32, d), bf), (cache, bf), (cache, bf), ((32,), jnp.int32))
+    assert kernels(text) == {"flash_decode"}
+    constraints = re.search(r"flash_decode[.\d]* = .*?operand_layout_"
+                            r"constraints=\{([^}]*\}[^}]*\}[^}]*\})", text)
+    assert constraints.group(1).split(", ")[1:] == [HELD_AS[d]] * 2
+    assert not copies(text, 32 * 8 * 8192 * d)
+
+
+def test_flash_decode_shares_the_copy_back_of_a_written_cache(
+        compile_for_chip):
+    """A decode step as a serving stage runs it: one row scattered into
+    each d = 160 cache, which XLA does in a layout of its own, then decode
+    over the caches, which are returned.  Each cache is copied into the
+    scatter's layout and back once, and the kernel reads the copy back:
+    no third copy into the kernel's layout."""
+    bf = jnp.bfloat16
+    cache = (32, 8, 8192, 160)
+
+    def step(q, k, v, rk, rv, n):
+        rows = jnp.arange(32)
+        k, v = k.at[rows, :, n].set(rk), v.at[rows, :, n].set(rv)
+        return ops.covenant_decode_attention(q, k, v, n + 1,
+                                             interpret=False), k, v
+
+    args = [((32, 32, 160), bf), (cache, bf), (cache, bf),
+            ((32, 8, 160), bf), ((32, 8, 160), bf), ((32,), jnp.int32)]
+    text = compile_for_chip(step, *args)
+    assert kernels(text) == {"flash_decode"}
+    assert len(copies(text, 32 * 8 * 8192 * 160)) == 4
+    call = re.search(r"flash_decode[.\d]* = [^\n]*?custom-call\(([^)]*)\)",
+                     text)
+    k_op, v_op = call.group(1).split(", ")[1:3]
+    # the entry computation comes last; its root returns the caches
+    returned = re.findall(r"ROOT [^\n]* tuple\(([^)]*)\)",
+                          text)[-1].split(", ")
+    for op in (k_op, v_op):
+        src = re.search(rf"{re.escape(op)} = \S+ bitcast\((%[\w.\-]+)\)",
+                        text)
+        assert src and src.group(1) in returned, (op, returned)
 
 
 def test_ssd_chunk_scan_compiles(compile_for_chip):

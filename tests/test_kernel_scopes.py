@@ -5,7 +5,8 @@ device time to the wrapper's pads, repeats and relayouts.
 
 Each wrapper is compiled on the CPU (Pallas interpreter) at shapes that
 need every step: a GEMM padded on m, n and k; GQA 8/2 with query and K/V
-lengths no block multiple; a decode cache no block multiple; an SSD with
+lengths no block multiple; a decode cache no block multiple, and one read
+slots-minor (head dim 160, slots a multiple of 128); an SSD with
 one group of four heads, a sequence no chunk multiple and an initial state;
 an expert layer holding 3 of 8 experts at widths no block multiple.
 """
@@ -28,7 +29,7 @@ INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?[\w.\-]+ = .*?\b([a-z][\w\-]*)\(.*?'
 S = jax.ShapeDtypeStruct
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
-# wrapper -> (its kernel's name, the call, argument shapes, the
+# wrapper[.case] -> (its kernel's name, the call, argument shapes, the
 # (step, opcode) pairs its compiled program must hold)
 CASES = {
     "covenant_matmul": (
@@ -50,6 +51,15 @@ CASES = {
         [S((2, 8, 32), BF), S((2, 2, 40, 32), BF), S((2, 2, 40, 32), BF),
          S((2,), I32)],
         {("repeat", "broadcast"), ("pad", "pad")}),
+    # d = 160 over 256 slots: the cache is read slots-minor, held to that
+    # layout by a constraint (a copy on the CPU) and relaid by bitcasts
+    "covenant_decode_attention.slots_minor": (
+        "flash_decode",
+        lambda q, k, v, n: ops.covenant_decode_attention(
+            q, k, v, n, block_kv=128, interpret=True),
+        [S((2, 8, 160), BF), S((2, 2, 256, 160), BF),
+         S((2, 2, 256, 160), BF), S((2,), I32)],
+        {("repeat", "broadcast"), ("layout", "copy")}),
     "covenant_ssd": (
         "ssd_chunk_scan",
         lambda x, dt, a, b, c, st: ops.covenant_ssd(
@@ -96,9 +106,10 @@ def test_step_names_are_not_harness_scopes():
     assert not any(s.split(".")[0] in HARNESS_SCOPES for s in STEPS)
 
 
-@pytest.mark.parametrize("fn", list(CASES))
-def test_wrapper_ops_carry_one_step(fn):
-    kernel, call, shapes, expected = CASES[fn]
+@pytest.mark.parametrize("case", list(CASES))
+def test_wrapper_ops_carry_one_step(case):
+    fn = case.split(".")[0]
+    kernel, call, shapes, expected = CASES[case]
     text = jax.jit(call).lower(*shapes).compile().as_text()
     ops_ = wrapper_steps(text, fn, kernel)
     assert ops_, f"no op of {fn} in the compiled program"

@@ -1,4 +1,6 @@
 """Pallas kernels vs ref.py oracles: shape/dtype sweeps (interpret=True)."""
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -118,14 +120,47 @@ def test_flash_attention_dtypes(dtype):
                                np.asarray(want, np.float32), atol=2e-2)
 
 
-def test_flash_decode_matches_ref():
-    b, hq, hkv, s, d = 3, 8, 2, 256, 32
+def pallas_operands(closed) -> list:
+    """The operand shapes of the first Pallas kernel in a jaxpr, nested
+    jits searched."""
+    for eqn in closed.jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return [x.aval.shape for x in eqn.invars]
+        for p in eqn.params.values():
+            if hasattr(p, "jaxpr") and hasattr(p, "consts"):
+                found = pallas_operands(p)
+                if found:
+                    return found
+    return []
+
+
+# (s, d, block_kv, lengths): a head dim no multiple of 128 over a cache of
+# 128-multiple slots is read slots-minor, (B·Hkv, D, S); any other shape
+# row-major, (B·Hkv, S, D)
+DECODE_CASES = {
+    "slots_minor_d32": (256, 32, 64, [100, 256, 17]),
+    "slots_minor_d160": (512, 160, 128, [1, 300, 512]),
+    "row_major_d128": (40, 128, 16, [1, 33, 40]),
+    "row_major_d32": (40, 32, 16, [40, 1, 21]),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_flash_decode_matches_ref(case):
+    s, d, block_kv, lens = DECODE_CASES[case]
+    b, hq, hkv = 3, 8, 2
     q = randn(b, hq, d)
     k = randn(b, hkv, s, d)
     v = randn(b, hkv, s, d)
-    kv_len = jnp.asarray([100, 256, 17])
-    got = ops.covenant_decode_attention(q, k, v, kv_len, block_kv=64,
-                                          interpret=True)
+    kv_len = jnp.asarray(lens)
+    call = functools.partial(ops.covenant_decode_attention,
+                             block_kv=block_kv, interpret=True)
+    kernel_k = pallas_operands(jax.make_jaxpr(call)(q, k, v, kv_len))[1]
+    # the head dim's axis; the slots' (padded to blocks) is the other
+    at = 1 if case.startswith("slots_minor") else 2
+    assert kernel_k[0] == b * hkv and kernel_k[at] == d, kernel_k
+    assert kernel_k[3 - at] >= s, kernel_k
+    got = call(q, k, v, kv_len)
     want = ref.attention_ref(q[:, :, None, :], k, v, causal=False,
                              kv_len=kv_len)[:, :, 0, :]
     np.testing.assert_allclose(got, want, atol=2e-3)
