@@ -75,77 +75,36 @@ def fig12(emit) -> dict:
     return table
 
 
-SEARCH = repro.SearchOptions(strategy="evolutionary", generations=4,
-                             population=10, seed=0, max_candidates=512)
+SEARCH = repro.SearchOptions(generations=4, population=10,
+                             max_candidates=512)
 
 
 def fig12_search(emit) -> dict:
     """Beyond-paper: §4's enabled search loop vs the one-shot heuristic —
-    now a driver option.  Each paper layer gets a "+search" (evolutionary)
-    and a "+beam" row under the SAME evaluation budget, so the rows double
-    as the cost-model-guided-vs-stochastic comparison; searched schedules
-    flow through the artifact cache/store like any other compile (a warm
-    REPRO_CACHE_DIR replays them without re-searching)."""
+    now a driver option.  Each paper layer gets a "+beam" row; searched
+    schedules flow through the artifact cache/store like any other compile
+    (a warm REPRO_CACHE_DIR replays them without re-searching)."""
     import dataclasses
 
     cfg = CONFIGS["+vec+pack+unroll"]
-    cfg_search = dataclasses.replace(cfg, search=SEARCH)
-    cfg_beam = dataclasses.replace(
-        cfg, search=dataclasses.replace(SEARCH, strategy="beam"))
+    cfg_beam = dataclasses.replace(cfg, search=SEARCH)
     gains = {}
-    beam_not_worse = 0
     for spec in library.PAPER_LAYERS:
         heur = repro.compile(spec, "hvx", cfg)
-        art = repro.compile(spec, "hvx", cfg_search)
-        bart = repro.compile(spec, "hvx", cfg_beam)
+        art = repro.compile(spec, "hvx", cfg_beam)
         gain = heur.cycles() / max(art.cycles(), 1e-9)
-        bgain = heur.cycles() / max(bart.cycles(), 1e-9)
         gains[spec.key] = gain
         evaluated = art.search.evaluated if art.search is not None else 0
-        bevaluated = bart.search.evaluated if bart.search is not None else 0
-        beam_not_worse += bart.cycles() <= art.cycles() + 1e-9
-        emit(f"fig12s/{spec.key}+search,0,search_gain=x{gain:.2f} "
+        emit(f"fig12s/{spec.key}+beam,0,beam_gain=x{gain:.2f} "
              f"evaluated={evaluated}")
-        emit(f"fig12s/{spec.key}+beam,0,beam_gain=x{bgain:.2f} "
-             f"evaluated={bevaluated}")
     gmean = math.exp(statistics.mean(math.log(max(g, 1e-9))
                                      for g in gains.values()))
     stats = repro.cache_stats()
     emit(f"fig12s/geomean,0,x{gmean:.2f}")
-    emit(f"fig12s/beam_not_worse,0,{beam_not_worse}/"
-         f"{len(library.PAPER_LAYERS)} layers at equal budget")
     emit(f"fig12s/cache,0,hits={stats['hits']} misses={stats['misses']} "
          f"store_hits={stats['store_hits']} "
          f"store_misses={stats['store_misses']}")
     return gains
-
-
-def fig15_race(emit, workers: int = 1) -> dict:
-    """Beyond-paper: the ``searches=`` racing axis — beam vs evolutionary
-    per layer under one budget through the sweep coordinator, winners
-    pinned in the store (the ISA-Mapper measurement-database pattern:
-    every later compile and warm-started search reuses them)."""
-    import dataclasses
-    import os
-    import tempfile
-
-    from repro.core import store as store_mod
-
-    store = os.environ.get(store_mod.ENV_DIR) \
-        or tempfile.mkdtemp(prefix="covenant-race-")
-    searches = [SEARCH, dataclasses.replace(SEARCH, strategy="beam")]
-    report = repro.sweep([s.key for s in library.PAPER_LAYERS[6:10]],
-                         ("hvx", "dnnweaver"), options=CONFIGS["+vec+pack+unroll"],
-                         searches=searches, workers=workers,
-                         store=store, race=True)
-    wins: dict[str, int] = {}
-    for pin in report.pins:
-        wins[pin["strategy"]] = wins.get(pin["strategy"], 0) + 1
-        emit(f"fig15/{pin['layer']}@{pin['target']},0,"
-             f"winner={pin['strategy']} cycles={pin['cycles']:.0f}")
-    for strat in sorted(wins):
-        emit(f"fig15/wins_{strat},0,{wins[strat]}/{len(report.pins)}")
-    return wins
 
 
 # Architecture family for the adaptability sweep (§2's headline claim as a
@@ -206,4 +165,4 @@ def fig13(emit) -> dict:
 
 
 __all__ = ["CONFIGS", "SEARCH", "VARIANTS", "fig11", "fig12", "fig12_search",
-           "fig13", "fig14_variants", "fig15_race", "layer_cycles"]
+           "fig13", "fig14_variants", "layer_cycles"]

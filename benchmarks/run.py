@@ -102,8 +102,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list of {fig11,fig12,fig12s,fig13,fig14,"
-                         "fig15,roofline,kernels,trajectory}")
-    ap.add_argument("--dryrun-dir", default="results/dryrun")
+                         "trajectory}")
     ap.add_argument("--expect-store-hits", action="store_true",
                     help="fail unless every compile was a disk-store hit "
                          "(requires REPRO_CACHE_DIR and a prior warm run)")
@@ -143,15 +142,6 @@ def main() -> None:
     if only is None or "fig14" in only:
         from benchmarks.paper_figs import fig14_variants
         fig14_variants(emit, workers=args.workers)
-    if only is None or "fig15" in only:
-        from benchmarks.paper_figs import fig15_race
-        fig15_race(emit, workers=args.workers)
-    if only is None or "kernels" in only:
-        from benchmarks.kernels_bench import run as krun
-        krun(emit)
-    if only is None or "roofline" in only:
-        from benchmarks.roofline_table import table
-        table(emit, args.dryrun_dir)
 
     snapshot = None
     if args.emit_json or args.baseline or (only and "trajectory" in only):

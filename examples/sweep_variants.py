@@ -11,8 +11,7 @@ coordinator reports them straight from the stored entries without
 dispatching a single worker (watch the ``dedup`` counts and the
 ``0 pipeline stages run`` summary).  The same sweep is scriptable as
 ``python -m repro.sweep`` (that is what the CI ``sweep-parallel`` job
-runs) and, claim-file-coordinated, as a fleet of independently launched
-``--external`` workers.
+runs).
 """
 import argparse
 import tempfile

@@ -6,8 +6,9 @@ vectorize / unroll / pack, macro-mnemonic ``codegen``) -> ``stream``
 execution, with ``interp`` (functional) and ``cost`` (analytic cycles) as
 cross-checks.  ``targets`` holds the predefined ACGs; ``driver`` is the
 user-facing ``repro.compile()`` entry point with the content-addressed
-compile cache, schedule ``search`` (a strategy registry materialising
-candidates through the pipeline) and the disk-backed ``store``.
+compile cache, schedule ``search`` (beam, with exhaustive as the oracle,
+materialising candidates through the pipeline) and the disk-backed
+``store``.
 ``scheduler.schedule`` / ``codegen.generate`` remain as thin stable
 wrappers over the pipeline stages.
 """

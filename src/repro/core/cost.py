@@ -292,8 +292,8 @@ def _compute_lower_bound(cdlt: Codelet, acg: ACG) -> tuple[float, str]:
 
 def _hop_traffic(cdlt: Codelet, acg: ACG, plans, committed: dict[str, int],
                  divisors: dict[str, list[int]],
-                 max_coalesce: int = 8) -> list[tuple[float, object]]:
-    """[(cycles lower bound, plan)] per operand, summed over its hops.
+                 max_coalesce: int = 8) -> float:
+    """Transfer cycles lower bound, summed over every operand's hops.
 
     Each hop's XFER-mnemonic count is bounded below by the max of three
     floors — bandwidth (bits moved / edge bandwidth), loads (one mnemonic
@@ -302,17 +302,16 @@ def _hop_traffic(cdlt: Codelet, acg: ACG, plans, committed: dict[str, int],
     factor up to ``max_coalesce``."""
     order = [l.var for l in cdlt.loops()]
     ranges = _loop_ranges(cdlt)
-    out = []
+    total = 0.0
     for p in plans:
         s = cdlt.surrogates[p.surrogate]
         elems, loads, rows = _operand_traffic_lb(cdlt, p, committed, order,
                                                  ranges, divisors)
         bits = elems * s.dtype.bits
-        cyc = sum(max(bits / e.bandwidth, loads,
-                      rows / max(max_coalesce, 1)) * e.latency
-                  for e, _ in p.hops(acg))
-        out.append((cyc, p))
-    return out
+        total += sum(max(bits / e.bandwidth, loads,
+                         rows / max(max_coalesce, 1)) * e.latency
+                     for e, _ in p.hops(acg))
+    return total
 
 
 def prefix_bounds(cdlt: Codelet, acg: ACG, plans, committed: dict[str, int],
@@ -325,9 +324,8 @@ def prefix_bounds(cdlt: Codelet, acg: ACG, plans, committed: dict[str, int],
         from .scheduler import _divisors
         divisors = {l.var: _divisors(l.trips) for l in cdlt.loops()}
     compute_lb, slot = _compute_lower_bound(cdlt, acg)
-    transfer_lb = sum(c for c, _ in
-                      _hop_traffic(cdlt, acg, plans, committed, divisors,
-                                   max_coalesce=max_coalesce))
+    transfer_lb = _hop_traffic(cdlt, acg, plans, committed, divisors,
+                               max_coalesce=max_coalesce)
     serial = compute_lb + transfer_lb
     if acg.issue_slots > 1:
         # packed streams overlap classes: bound by the slowest slot class
@@ -358,28 +356,5 @@ def prefix_bound(cdlt: Codelet, acg: ACG, plans, committed: dict[str, int],
     return packed if pack else serial
 
 
-def transfer_hot_vars(cdlt: Codelet, acg: ACG, plans,
-                      tiling: dict[str, int],
-                      divisors: dict[str, list[int]] | None = None
-                      ) -> list[str]:
-    """Loop vars of the operand whose staging edges dominate transfer
-    cycles under ``tiling`` — the loops transfer-aware mutation biases
-    toward.  Deterministic (sorted) for seed-stable search."""
-    if divisors is None:
-        divisors = {}
-    ranked = sorted(_hop_traffic(cdlt, acg, plans, tiling, divisors),
-                    key=lambda cp: -cp[0])
-    for cyc, p in ranked:
-        if cyc <= 0:
-            break
-        vs = set()
-        for ix in p.ref.idx:
-            vs |= ix.vars()
-        hot = sorted(vs & set(tiling))
-        if hot:
-            return hot
-    return []
-
-
 __all__ = ["CostReport", "cost", "prefix_bound", "prefix_bounds",
-           "transfer_cost", "transfer_hot_vars"]
+           "transfer_cost"]

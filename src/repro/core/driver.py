@@ -360,7 +360,7 @@ def _restore_from_store(entry: dict, cdlt: Codelet, acg: ACG,
             heuristic_cycles=float(s["heuristic_cycles"]),
             evaluated=int(s["evaluated"]),
             trace=[tuple(t) for t in s.get("trace", [])],
-            strategy=s.get("strategy", "evolutionary"), point=s.get("point"),
+            strategy=s.get("strategy", "beam"), point=s.get("point"),
             seeded=int(s.get("seeded", 0)), space_sig=s.get("space_sig"))
     return art
 

@@ -59,14 +59,12 @@ class CompileOptions:
     pack: bool = True
     unroll_factor: int = 4
     max_mnemonics: int = 300_000
-    check_covenant: bool = True    # run the early covenant-validation stage
     search: object | None = None   # SearchOptions; None = one-shot heuristic
     store: object | None = None    # ArtifactStore | path; not fingerprinted
 
     def fingerprint(self) -> str:
         base = repr((self.vectorize, self.unroll, self.pack,
-                     self.unroll_factor, self.max_mnemonics,
-                     self.check_covenant))
+                     self.unroll_factor, self.max_mnemonics))
         if self.search is not None:
             fp = getattr(self.search, "fingerprint", None)
             base += ";search=" + (fp() if fp else repr(self.search))
@@ -125,10 +123,7 @@ def covenant_stage(ctx: PassContext) -> None:
     supporting capability, an encodable mnemonic and a viable staging
     route *before* scheduling starts, so a broken covenant surfaces as a
     named ``CovenantError`` diagnostic instead of a KeyError deep in
-    tiling or codegen.  Disable with ``CompileOptions(check_covenant=
-    False)``."""
-    if not getattr(ctx.options, "check_covenant", True):
-        return
+    tiling or codegen."""
     from .covenant import check_covenant
     check_covenant(ctx.cdlt, ctx.acg, options=ctx.options)
 

@@ -30,15 +30,12 @@ processes over one store):
 * LRU eviction is serialised by a store-wide ``FileLock`` and never
   touches a *foreign* entry younger than ``FRESH_GRACE`` seconds, so two
   concurrently-evicting processes cannot delete each other's fresh puts;
-* sweep workers claim work units through per-entry claim files
-  (``claim()`` / ``release_claim()``) with a stale-claim timeout, so a
-  crashed worker's units are reclaimed instead of lost;
 * every compile a sweep performs is recorded in a monotonic, append-only
   ``SweepJournal`` (one JSON line per event, sequence numbers issued
   under the lock) — CI asserts "each work unit compiled exactly once"
   straight off the journal;
-* ``gc()`` reclaims by age and size and reaps orphaned tmp/lock/claim
-  files.
+* ``gc()`` reclaims by age and size and reaps orphaned tmp files and
+  aged sweep directories.
 """
 from __future__ import annotations
 
@@ -87,7 +84,7 @@ _SIGNATURE: str | None = None
 
 
 def _break_stale(path: str) -> bool:
-    """Remove a stale lock/claim file *atomically claimed for removal*:
+    """Remove a stale lock file *atomically claimed for removal*:
     rename-to-unique first, so of two breakers exactly one wins and
     neither can ever delete the file a third process just re-created
     under the original name (the stat-then-remove TOCTOU)."""
@@ -248,8 +245,7 @@ class ArtifactStore:
         self.max_bytes = max_bytes
         os.makedirs(self.root, exist_ok=True)
         self.stats = {"hits": 0, "misses": 0, "puts": 0, "evictions": 0,
-                      "corrupt": 0, "stale": 0, "claims": 0, "reclaims": 0,
-                      "claim_losses": 0}
+                      "corrupt": 0, "stale": 0}
         # entry paths THIS process wrote: eviction may reap our own fresh
         # entries (the size bound is ours to keep) but never a foreign
         # entry younger than FRESH_GRACE — see the multi-writer contract
@@ -434,12 +430,11 @@ class ArtifactStore:
                 pass
         for d in self.sweep_dirs():
             shutil.rmtree(d, ignore_errors=True)
-        shutil.rmtree(os.path.join(self.root, "pins"), ignore_errors=True)
         self._approx_bytes = 0
 
-    # -- sweep coordination (claims + journals) ------------------------------
+    # -- sweep journals ------------------------------------------------------
     def sweep_dir(self, sweep_id: str, create: bool = True) -> str:
-        """Scratch directory of one sweep (claims, journal) under the
+        """Scratch directory of one sweep (its journal) under the
         store root — shared state travels with the measurement database."""
         assert sweep_id and "/" not in sweep_id and ".." not in sweep_id, \
             sweep_id
@@ -460,69 +455,15 @@ class ArtifactStore:
     def journal(self, sweep_id: str) -> SweepJournal:
         return SweepJournal(self, sweep_id)
 
-    def _claim_path(self, sweep_id: str, key: str) -> str:
-        return os.path.join(self.sweep_dir(sweep_id), key + ".claim")
-
-    def claim(self, sweep_id: str, key: str, owner: str,
-              stale_timeout: float = 60.0) -> bool:
-        """Try to claim work unit ``key`` of ``sweep_id`` for ``owner``.
-
-        Exactly one live claimer wins (``O_CREAT|O_EXCL``).  A claim left
-        behind by a crashed worker is broken once older than
-        ``stale_timeout`` seconds, so its units are *reclaimed* — the
-        sweep always drains."""
-        path = self._claim_path(sweep_id, key)
-        reclaimed = False
-        while True:
-            try:
-                fd = os.open(path, os.O_CREAT | os.O_EXCL | os.O_WRONLY)
-            except FileExistsError:
-                try:
-                    age = _time.time() - os.stat(path).st_mtime
-                except OSError:
-                    continue  # released under us: retry the O_EXCL attempt
-                if age > stale_timeout:
-                    # break the dead worker's claim; _break_stale's atomic
-                    # rename guarantees a racing breaker can never delete
-                    # a claim some third worker just re-won
-                    reclaimed = _break_stale(path) or reclaimed
-                    continue
-                self.stats["claim_losses"] += 1
-                return False
-            with os.fdopen(fd, "w", encoding="utf-8") as f:
-                f.write(json.dumps({"owner": owner, "pid": os.getpid(),
-                                    "time": _time.time()}))
-            self.stats["claims"] += 1
-            if reclaimed:
-                self.stats["reclaims"] += 1
-            return True
-
-    def release_claim(self, sweep_id: str, key: str, owner: str) -> None:
-        """Drop ``owner``'s claim.  A claim re-issued to someone else
-        after ours went stale is left alone."""
-        path = self._claim_path(sweep_id, key)
-        try:
-            with open(path, "r", encoding="utf-8") as f:
-                if json.load(f).get("owner") != owner:
-                    return
-        except (OSError, ValueError):
-            return
-        try:
-            os.remove(path)
-        except OSError:
-            pass
-
     def gc(self, max_age: float | None = None,
-           max_bytes: int | None = None,
-           claim_timeout: float = 3600.0) -> dict:
-        """Reclaim disk: drop entries older than ``max_age`` seconds, then
-        LRU-evict down to ``max_bytes`` (default: the store's own bound),
-        and reap orphaned ``.tmp`` files, stale claim files and sweep
-        scratch dirs older than ``max_age``.  Returns counts."""
+           max_bytes: int | None = None) -> dict:
+        """Reclaim disk: drop entries and sweep scratch dirs older than
+        ``max_age`` seconds, then LRU-evict down to ``max_bytes`` (default:
+        the store's own bound), which also reaps orphaned ``.tmp`` files.
+        Returns counts."""
         import shutil
         now = _time.time()
-        out = {"aged": 0, "evicted": 0, "claims_reaped": 0,
-               "sweeps_reaped": 0}
+        out = {"aged": 0, "evicted": 0, "sweeps_reaped": 0}
         if max_age is not None:
             for p in self._entries():
                 try:
@@ -532,84 +473,17 @@ class ArtifactStore:
                         out["aged"] += 1
                 except OSError:
                     pass
-        for d in self.sweep_dirs():
-            try:
-                if max_age is not None \
-                        and now - os.stat(d).st_mtime > max_age:
-                    shutil.rmtree(d, ignore_errors=True)
-                    out["sweeps_reaped"] += 1
-                    continue
-            except OSError:
-                continue
-            for n in os.listdir(d):
-                if not n.endswith(".claim"):
-                    continue
-                p = os.path.join(d, n)
+            for d in self.sweep_dirs():
                 try:
-                    if now - os.stat(p).st_mtime > claim_timeout:
-                        os.remove(p)
-                        out["claims_reaped"] += 1
+                    if now - os.stat(d).st_mtime > max_age:
+                        shutil.rmtree(d, ignore_errors=True)
+                        out["sweeps_reaped"] += 1
                 except OSError:
                     pass
         before = self.stats["evictions"]
         self._evict(max_bytes=max_bytes)
         out["evicted"] = self.stats["evictions"] - before
         self._approx_bytes = self.size_bytes()
-        return out
-
-    # -- race pins -----------------------------------------------------------
-    def _pin_dir(self, create: bool = True) -> str:
-        d = os.path.join(self.root, "pins")
-        if create:
-            os.makedirs(d, exist_ok=True)
-        return d
-
-    @staticmethod
-    def pin_name(layer: str, target: str) -> str:
-        raw = f"{layer}@{target}"
-        return "".join(c if c.isalnum() or c in "@=-_.,x" else "_"
-                       for c in raw)
-
-    def pin(self, name: str, record: dict) -> None:
-        """Atomically record a race winner (or any named best-point
-        digest) under ``<root>/pins/<name>.json`` — the ``searches=``
-        racing sweep pins each (layer, target)'s winning strategy/point
-        here, and the warm-start index treats pins as prime seeds."""
-        path = os.path.join(self._pin_dir(), name + _SUFFIX)
-        fd, tmp = tempfile.mkstemp(dir=self._pin_dir(), suffix=".tmp")
-        try:
-            with os.fdopen(fd, "w", encoding="utf-8") as f:
-                json.dump(dict(record, pin=name, time=_time.time()), f)
-            os.replace(tmp, path)
-        except BaseException:
-            try:
-                os.remove(tmp)
-            except OSError:
-                pass
-            raise
-
-    def load_pin(self, name: str) -> dict | None:
-        try:
-            with open(os.path.join(self._pin_dir(create=False),
-                                   name + _SUFFIX),
-                      "r", encoding="utf-8") as f:
-                return json.load(f)
-        except (OSError, ValueError):
-            return None
-
-    def pins(self) -> dict[str, dict]:
-        """{pin name: record} of every readable pin."""
-        out = {}
-        try:
-            names = os.listdir(self._pin_dir(create=False))
-        except FileNotFoundError:
-            return out
-        for n in sorted(names):
-            if not n.endswith(_SUFFIX):
-                continue
-            rec = self.load_pin(n[:-len(_SUFFIX)])
-            if rec is not None:
-                out[n[:-len(_SUFFIX)]] = rec
         return out
 
     # -- introspection -------------------------------------------------------
@@ -647,7 +521,7 @@ class WarmStartIndex:
 
     Built from the store's sweep journals (every (layer, variant, cycles)
     point a fleet ever measured) joined with the stored entries that
-    carry the actual tiling/unroll decisions, plus race pins.  Searching
+    carry the actual tiling/unroll decisions.  Searching
     a new layer asks ``seeds(space, ...)``: points from layers whose
     schedule space has the *same shape* (equal ``space.signature()``)
     transfer verbatim; points without a recorded signature are admitted
@@ -700,12 +574,6 @@ class WarmStartIndex:
             s = entry.get("search") or {}
             idx.add(cycles, s.get("space_sig"), entry["tiling"],
                     entry.get("unroll_factor", 1), tie=k)
-        for name, rec in store.pins().items():
-            point = rec.get("point") or {}
-            if point.get("tiling") and rec.get("cycles") is not None:
-                idx.add(rec["cycles"], rec.get("space_sig"),
-                        point["tiling"], point.get("unroll_factor", 1),
-                        tie=f"pin:{name}")
         return idx
 
     @classmethod
@@ -713,16 +581,10 @@ class WarmStartIndex:
         """``from_store`` memoised on the store instance: rebuilding scans
         every journal and peeks up to 1024 entries, far too much to repeat
         per warm-started compile of a sweep.  The cache key is a cheap
-        directory census (entry/sweep/pin counts + this process's puts —
+        directory census (entry/sweep counts + this process's puts —
         counting, never parsing, files), so foreign writers invalidate it
         as soon as their files land."""
-        try:
-            n_pins = sum(n.endswith(_SUFFIX)
-                         for n in os.listdir(store._pin_dir(create=False)))
-        except FileNotFoundError:
-            n_pins = 0
-        census = (store.stats["puts"], len(store), len(store.sweep_dirs()),
-                  n_pins)
+        census = (store.stats["puts"], len(store), len(store.sweep_dirs()))
         cached = getattr(store, "_warm_index", None)
         if cached is not None and cached[0] == census:
             return cached[1]

@@ -21,17 +21,12 @@ identity is what makes the coordinator correct by construction:
   configured, so results land in the shared measurement database and the
   coordinator's ``SweepReport`` is just the union of unit records.
 
-Three backends:
+Two backends:
 
 * ``serial`` — in-process, the reference semantics (``SweepReport`` merge
   identity vs a plain ``compile_many`` is a test invariant);
 * ``process`` — the coordinator forks/spawns N workers
-  (``multiprocessing``) over a static partition;
-* ``external`` — *this* process is one of N independently launched
-  workers (``python -m repro.sweep ... --external``) that claim units
-  through store-side claim files (``ArtifactStore.claim``) with a
-  stale-claim timeout, so a crashed worker's units are reclaimed by the
-  survivors and the sweep always drains.
+  (``multiprocessing``) over a static partition.
 
 Every unit outcome is appended to the store's monotonic ``SweepJournal``;
 CI asserts "each work unit compiled exactly once, warm re-runs recompile
@@ -43,7 +38,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
-import os
 from typing import Iterable, Sequence
 
 from . import library as library_mod
@@ -123,11 +117,11 @@ def _workload_label(workload: tuple) -> str:
 
 
 # ---------------------------------------------------------------------------
-# options (de)serialisation — JSON plans for external/spawned workers
+# options (de)serialisation — JSON plans for spawned workers
 # ---------------------------------------------------------------------------
 
 _OPTION_FIELDS = ("vectorize", "unroll", "pack", "unroll_factor",
-                  "max_mnemonics", "check_covenant")
+                  "max_mnemonics")
 
 
 def options_to_json(opts: CompileOptions) -> dict:
@@ -150,8 +144,7 @@ def options_from_json(d: dict) -> CompileOptions:
 def _options_label(opts: CompileOptions) -> str:
     if opts.search is not None:
         return (f"search:{opts.search.strategy}"
-                f"@g{opts.search.generations}p{opts.search.population}"
-                f"s{opts.search.seed}")
+                f"@g{opts.search.generations}p{opts.search.population}")
     return "heuristic"
 
 
@@ -227,14 +220,12 @@ class SweepReport:
     ``merge`` is associative and idempotent over unit keys (the best
     record per key wins: ok > failed > skipped), so partial reports from
     any number of workers — or from a re-run — combine into the same
-    final report.  ``pins`` records the winners a ``race=True`` sweep
-    pinned in the store (coordinator-side, attached after the merge)."""
+    final report."""
 
     sweep_id: str
     results: list[UnitResult] = dataclasses.field(default_factory=list)
     backend: str = "serial"
     workers: int = 1
-    pins: list[dict] = dataclasses.field(default_factory=list)
 
     # -- roll-ups ------------------------------------------------------------
     def counts(self) -> dict:
@@ -281,27 +272,14 @@ class SweepReport:
                          f"{r.opt:>24s} {r.cycles:14.0f}")
         return "\n".join(lines)
 
-    def race_table(self) -> str:
-        """Human-readable table of the strategy race winners (``pins``)."""
-        if not self.pins:
-            return "(no race winners pinned)"
-        width = max(len(p["layer"]) for p in self.pins)
-        lines = [f"{'layer':{width}s} {'target':>24s} {'winner':>14s} "
-                 f"{'cycles':>14s}"]
-        for p in sorted(self.pins, key=lambda p: (p["layer"], p["target"])):
-            lines.append(f"{p['layer']:{width}s} {p['target']:>24s} "
-                         f"{p['strategy']:>14s} {p['cycles']:14.0f}")
-        return "\n".join(lines)
-
     def summary(self) -> str:
         c = self.counts()
-        pinned = f", {len(self.pins)} winners pinned" if self.pins else ""
         return (f"sweep {self.sweep_id}: {c['units']} units via "
                 f"{self.backend}x{self.workers} — {c['ok']} ok "
                 f"({c['compiled']} compiled, {c['store']} store, "
                 f"{c['cache']} cache, {c['dedup']} dedup), "
                 f"{c['failed']} failed, {c['skipped']} skipped, "
-                f"{self.stages_run()} pipeline stages run{pinned}")
+                f"{self.stages_run()} pipeline stages run")
 
     # -- merge ---------------------------------------------------------------
     @classmethod
@@ -326,13 +304,13 @@ class SweepReport:
     # -- (de)serialisation ---------------------------------------------------
     def to_json(self) -> dict:
         return {"sweep_id": self.sweep_id, "backend": self.backend,
-                "workers": self.workers, "pins": list(self.pins),
+                "workers": self.workers,
                 "results": [r.to_json() for r in self.results]}
 
     @classmethod
     def from_json(cls, d: dict) -> "SweepReport":
         return cls(sweep_id=d["sweep_id"], backend=d.get("backend", "?"),
-                   workers=d.get("workers", 1), pins=d.get("pins", []),
+                   workers=d.get("workers", 1),
                    results=[UnitResult.from_json(r) for r in d["results"]])
 
     def save(self, path: str) -> None:
@@ -537,153 +515,6 @@ def _process_backend(shards: list[list[WorkUnit]], store, sweep_id: str,
 
 
 # ---------------------------------------------------------------------------
-# external (claim-based) backend
-# ---------------------------------------------------------------------------
-
-
-class _ClaimHeartbeat:
-    """Touch a held claim file on a background timer while its unit
-    compiles, so a unit that legitimately takes longer than the
-    stale-claim timeout (search-enabled compiles, huge layers) is never
-    mistaken for a crashed worker's and double-compiled.  A worker that
-    really dies stops beating, its claim ages out, and the unit is
-    reclaimed — exactly the intended split."""
-
-    def __init__(self, path: str, interval: float):
-        import threading
-        self.path = path
-        self.interval = max(interval, 0.05)
-        self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._beat, daemon=True)
-
-    def _beat(self) -> None:
-        while not self._stop.wait(self.interval):
-            try:
-                os.utime(self.path, None)
-            except OSError:
-                return  # claim gone (released/broken): nothing to keep warm
-
-    def __enter__(self) -> "_ClaimHeartbeat":
-        self._thread.start()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._stop.set()
-        self._thread.join(timeout=5)
-
-
-def run_external_worker(units: Sequence[WorkUnit], store, worker: str,
-                        sweep_id: str | None = None,
-                        stale_claim_timeout: float = 60.0,
-                        drain_timeout: float | None = None) -> SweepReport:
-    """Act as one independently launched worker of a fleet: walk the plan
-    in key order, skip units already stored, claim the rest through
-    store-side claim files, compile, journal, release.  Claims older than
-    ``stale_claim_timeout`` (a crashed worker) are broken and reclaimed;
-    held claims are heartbeat-refreshed while their unit compiles.
-
-    Units another live worker holds are re-visited until they appear in
-    the store (that worker finished) or their claim goes stale and is
-    reclaimed (that worker died) — so the *last surviving* worker still
-    drains the whole plan.  ``drain_timeout`` (default: 10x the stale
-    timeout) bounds that wait; units still held by a live-and-beating
-    claim when it expires are reported ``skipped``."""
-    import time as time_mod
-
-    if store is None:
-        raise ValueError("external workers need a shared ArtifactStore")
-    sweep_id = sweep_id or plan_id(units)
-    if drain_timeout is None:
-        drain_timeout = 10 * stale_claim_timeout
-    journal = store.journal(sweep_id)
-    done: dict[str, UnitResult] = {}
-    pending = sorted(units, key=lambda u: u.key)
-    deadline = time_mod.monotonic() + drain_timeout
-    while pending:
-        waiting = []
-        for unit in pending:
-            entry = store.peek(unit.key)
-            if entry is not None:
-                done[unit.key] = _dedup_result(unit, entry, worker)
-                continue
-            if not store.claim(sweep_id, unit.key, worker,
-                               stale_timeout=stale_claim_timeout):
-                done[unit.key] = UnitResult(
-                    key=unit.key, layer=unit.layer, target=unit.target,
-                    opt=unit.opt, status="skipped", source="none",
-                    worker=worker, error="claimed by another worker")
-                waiting.append(unit)
-                continue
-            try:
-                with _ClaimHeartbeat(store._claim_path(sweep_id, unit.key),
-                                     stale_claim_timeout / 3):
-                    done[unit.key] = _compile_unit(unit, store, journal,
-                                                   worker)
-            finally:
-                store.release_claim(sweep_id, unit.key, worker)
-        pending = waiting
-        if pending and time_mod.monotonic() >= deadline:
-            break
-        if pending:
-            time_mod.sleep(min(1.0, stale_claim_timeout / 4))
-    results = [done[k] for k in sorted(done)]
-    return SweepReport(sweep_id=sweep_id, results=results,
-                       backend="external", workers=1)
-
-
-# ---------------------------------------------------------------------------
-# strategy racing — pin the per-(layer, target) winner in the store
-# ---------------------------------------------------------------------------
-
-
-def _pin_race_winners(units: Sequence[WorkUnit], report: SweepReport,
-                      store, journal) -> list[dict]:
-    """Race the ``searches=`` axis: among each (layer, target)'s search
-    units pick the lowest-cycles winner, write it as a store pin
-    (``ArtifactStore.pin``) and journal a ``pinned`` event.  Returns the
-    pin records (also attached to the report).  Winners feed the
-    warm-start index, so a race permanently upgrades later searches of
-    same-shaped layers."""
-    reported = {r.key: r for r in report.ok if r.cycles is not None}
-    groups: dict[tuple[str, str], list[tuple[float, WorkUnit]]] = {}
-    for u in units:
-        if u.options.search is None:
-            continue
-        # trust the store over this worker's partial view: a unit another
-        # fleet member compiled (this report says skipped/failed) must
-        # still race, or a drain-timeout could pin the losing strategy
-        r = reported.get(u.key)
-        cycles = r.cycles if r is not None else \
-            store_mod.entry_cycles(store.peek(u.key) or {})
-        if cycles is None:
-            continue
-        groups.setdefault((u.layer, u.target), []).append((cycles, u))
-    pins: list[dict] = []
-    for (layer, target), cs in sorted(groups.items()):
-        # a rival strategy failing must not cost the group its pin: the
-        # surviving strategies still raced (the plan guaranteed >= 2),
-        # and the best of them is strictly better than no record at all
-        cycles, unit = min(cs, key=lambda cu: (cu[0], cu[1].key))
-        entry = store.peek(unit.key) or {}
-        search = entry.get("search") or {}
-        rec = {"layer": layer, "target": target, "key": unit.key,
-               "strategy": unit.options.search.strategy,
-               "opt": unit.opt, "cycles": cycles,
-               "point": {"tiling": entry.get("tiling"),
-                         "unroll_factor": entry.get("unroll_factor", 1)},
-               "space_sig": search.get("space_sig"),
-               "raced": sorted(u.opt for _, u in cs)}
-        store.pin(store.pin_name(layer, target), rec)
-        pins.append(rec)
-        _journal_safe(journal, {"event": "pinned", "key": unit.key,
-                                "layer": layer, "target": target,
-                                "worker": "coordinator",
-                                "cycles": cycles,
-                                "strategy": rec["strategy"]})
-    return pins
-
-
-# ---------------------------------------------------------------------------
 # the coordinator
 # ---------------------------------------------------------------------------
 
@@ -693,8 +524,6 @@ def sweep(layers: Iterable, targets: Sequence[str] = ("hvx",), *,
           searches: Sequence[SearchOptions | None] | None = None,
           workers: int = 1, store=None, backend: str | None = None,
           sweep_id: str | None = None, dedup: bool = True,
-          race: bool = False,
-          stale_claim_timeout: float = 60.0,
           mp_start: str | None = None) -> SweepReport:
     """Run a sweep plan and merge the outcome into a ``SweepReport``.
 
@@ -708,37 +537,15 @@ def sweep(layers: Iterable, targets: Sequence[str] = ("hvx",), *,
     database; with one configured, already-stored units are *deduplicated*
     (reported, not dispatched) and every worker compile lands in the store
     and the sweep journal.  ``backend`` defaults to ``process`` when
-    ``workers > 1`` else ``serial``; ``external`` turns this process into
-    one claim-based worker of an independently launched fleet.
-
-    ``race=True`` treats the ``searches=`` axis as a per-layer strategy
-    race: every strategy runs under its own (equal) budget, and each
-    (layer, target)'s lowest-cycles winner is *pinned* in the store
-    (``report.pins`` / ``report.race_table()``) for later compiles and
-    warm-started searches to reuse."""
+    ``workers > 1`` else ``serial``."""
     if store is None and options is not None \
             and getattr(options, "store", None) is not None:
         store = options.store  # honour the compile()/compile_many() idiom
     st = store_mod.resolve(store)
-    if race:
-        if st is None:
-            raise ValueError("race=True needs a shared ArtifactStore to "
-                             "pin winners in")
-        if not searches or sum(s is not None for s in searches) < 2:
-            raise ValueError("race=True needs a searches= axis of at "
-                             "least two strategies to race")
     units = expand_plan(layers, targets, options=options, searches=searches)
     sweep_id = sweep_id or plan_id(units)
     if backend is None:
         backend = "process" if workers > 1 else "serial"
-    if backend == "external":
-        report = run_external_worker(units, st, worker=f"pid{os.getpid()}",
-                                     sweep_id=sweep_id,
-                                     stale_claim_timeout=stale_claim_timeout)
-        if race:
-            report.pins = _pin_race_winners(units, report, st,
-                                            st.journal(sweep_id))
-        return report
 
     results: list[UnitResult] = []
     todo: list[WorkUnit] = []
@@ -780,12 +587,9 @@ def sweep(layers: Iterable, targets: Sequence[str] = ("hvx",), *,
         sweep_id=sweep_id)
     report.backend = backend
     report.workers = workers
-    if race:
-        report.pins = _pin_race_winners(units, report, st, journal)
     return report
 
 
 __all__ = ["SweepReport", "UnitResult", "WorkUnit", "build_workload",
            "expand_plan", "options_from_json", "options_to_json",
-           "partition", "plan_id", "run_external_worker", "sweep",
-           "workload_of"]
+           "partition", "plan_id", "sweep", "workload_of"]

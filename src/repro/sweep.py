@@ -19,8 +19,7 @@ CI contract flags: ``--assert-unique-compiles`` fails unless the sweep
 journal shows every work unit compiled *exactly once* (across cold + warm
 runs of the same plan); ``--expect-store-hits`` fails unless every unit
 was served from the store with zero pipeline stages executed (the warm
-re-run check).  ``--external`` makes this process one claim-based worker
-of an independently launched fleet instead of a forking coordinator.
+re-run check).
 """
 from __future__ import annotations
 
@@ -29,12 +28,12 @@ import types
 
 from repro.core.store import ArtifactStore, SweepJournal, WarmStartIndex
 from repro.core.sweep import (SweepReport, UnitResult, WorkUnit,
-                              expand_plan, partition, plan_id,
-                              run_external_worker, sweep, workload_of)
+                              expand_plan, partition, plan_id, sweep,
+                              workload_of)
 
 __all__ = ["ArtifactStore", "SweepJournal", "SweepReport", "UnitResult",
            "WarmStartIndex", "WorkUnit", "expand_plan", "partition",
-           "plan_id", "run_external_worker", "sweep", "workload_of"]
+           "plan_id", "sweep", "workload_of"]
 
 
 class _CallableModule(types.ModuleType):
@@ -57,7 +56,7 @@ sys.modules[__name__].__class__ = _CallableModule
 def _parse_search(text: str):
     """``strategy=beam,generations=4,population=10,beam_width=8,
     warm_start=1`` -> SearchOptions; a bare strategy name is shorthand
-    (``beam`` == ``strategy=beam``)."""
+    (``exhaustive`` == ``strategy=exhaustive``)."""
     from repro.core.search import STRATEGIES, SearchOptions
     kwargs: dict = {}
     for part in text.split(","):
@@ -70,14 +69,15 @@ def _parse_search(text: str):
                 kwargs["strategy"] = k
                 continue
             raise ValueError(
-                f"--search: {k!r} is neither a registered strategy "
-                f"({sorted(STRATEGIES)}) nor a K=V setting")
+                f"--search: {k!r} is neither a strategy "
+                f"({list(STRATEGIES)}) nor a K=V setting")
         if k == "strategy":
+            if v.strip() not in STRATEGIES:
+                raise ValueError(f"--search: unknown strategy {v!r} "
+                                 f"(known: {list(STRATEGIES)})")
             kwargs[k] = v.strip()
         elif k == "warm_start":
             kwargs[k] = v.strip().lower() in ("1", "true", "yes")
-        elif k == "patience":
-            kwargs[k] = None if v.strip().lower() == "none" else int(v)
         else:
             try:
                 kwargs[k] = int(v)
@@ -108,24 +108,15 @@ def _main(argv=None) -> int:
                          "variants like dnnweaver@pe=32x32")
     ap.add_argument("--workers", type=int, default=1)
     ap.add_argument("--backend", default=None,
-                    choices=("serial", "process", "external"))
-    ap.add_argument("--external", action="store_true",
-                    help="act as one claim-based worker of an "
-                         "independently launched fleet")
+                    choices=("serial", "process"))
     ap.add_argument("--store", default=None,
                     help="artifact-store directory "
                          "(default: $REPRO_CACHE_DIR)")
     ap.add_argument("--search", action="append", default=None,
                     metavar="K=V,...",
                     help="add a search axis entry (repeatable), e.g. "
-                         "'strategy=evolutionary,generations=4,"
-                         "population=10,seed=0' or just 'beam'; repeat "
-                         "the flag to race several strategies")
-    ap.add_argument("--race", action="store_true",
-                    help="race the --search strategies per (layer, "
-                         "target) under equal budgets and pin each "
-                         "winner in the store")
-    ap.add_argument("--stale-claim-timeout", type=float, default=60.0)
+                         "'strategy=beam,generations=4,population=10' "
+                         "or just 'exhaustive'")
     ap.add_argument("--no-dedup", action="store_true",
                     help="dispatch already-stored units anyway (they "
                          "still warm-restore inside the workers)")
@@ -145,13 +136,11 @@ def _main(argv=None) -> int:
         else [s.key for s in library.PAPER_LAYERS]
     targets = args.targets.split(",")
     store = args.store or os.environ.get(store_mod.ENV_DIR)
-    needs_store = (args.external or args.backend == "external"
-                   or args.assert_unique_compiles
-                   or args.expect_store_hits or args.workers > 1
-                   or args.race)
+    needs_store = (args.assert_unique_compiles or args.expect_store_hits
+                   or args.workers > 1)
     if store is None and needs_store:
-        print("error: multi-worker / journal-asserted / racing sweeps need "
-              "a store (--store DIR or REPRO_CACHE_DIR)", file=sys.stderr)
+        print("error: multi-worker / journal-asserted sweeps need a store "
+              "(--store DIR or REPRO_CACHE_DIR)", file=sys.stderr)
         return 2
     st = store_mod.resolve(store) if store else None
     if st is not None and args.gc_max_age is not None:
@@ -162,16 +151,9 @@ def _main(argv=None) -> int:
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
-    if args.race and (not searches or len(searches) < 2):
-        print("error: --race needs at least two --search strategies",
-              file=sys.stderr)
-        return 2
-    backend = args.backend or ("external" if args.external else None)
 
     report = sweep(layers, targets, searches=searches, workers=args.workers,
-                   store=st, backend=backend, dedup=not args.no_dedup,
-                   race=args.race,
-                   stale_claim_timeout=args.stale_claim_timeout)
+                   store=st, backend=args.backend, dedup=not args.no_dedup)
 
     for r in report.results:
         cyc = f"{r.cycles:.0f}" if r.cycles is not None else "-"
@@ -182,9 +164,6 @@ def _main(argv=None) -> int:
         print(line)
     print()
     print(report.best_table())
-    if args.race:
-        print()
-        print(report.race_table())
     print()
     print(report.summary())
     if args.json:

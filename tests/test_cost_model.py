@@ -215,17 +215,3 @@ def test_prefix_bound_is_deterministic():
     a = [cost.prefix_bound(space.probe, acg, space.plans, committed,
                            divisors=space.divisors) for _ in range(3)]
     assert len(set(a)) == 1
-
-
-def test_transfer_hot_vars_names_dominant_operand_loops():
-    """On a reload-heavy tiling the hot vars are loop vars of the operand
-    with the dominant staging traffic — and always a subset of the
-    tiling's loops (mutation can act on every one of them)."""
-    acg = targets.get_target("hvx")
-    cdlt = library.gemm(24, 32, 16, in_dtype="u8")
-    space = _space(cdlt, acg)
-    worst = {v: 1 for v in space.loop_order()}
-    hot = cost.transfer_hot_vars(space.probe, acg, space.plans, worst,
-                                 divisors=space.divisors)
-    assert hot and set(hot) <= set(worst)
-    assert hot == sorted(hot)  # deterministic order for seed-stable search

@@ -186,7 +186,7 @@ def test_search_option_routes_through_driver():
     """CompileOptions(search=...) produces a cached artifact with the trace
     attached, keyed separately from the heuristic compile."""
     repro.clear_cache()
-    sopts = repro.SearchOptions(generations=3, population=8, seed=0)
+    sopts = repro.SearchOptions(generations=3, population=8)
     cdlt = library.gemm(24, 32, 16, in_dtype="u8")
     heur = repro.compile(cdlt, "hvx")
     art = repro.compile(cdlt, "hvx", repro.CompileOptions(search=sopts))
@@ -228,7 +228,7 @@ def test_store_option_accepts_path(tmp_path):
 def test_search_option_must_be_search_options():
     with pytest.raises(TypeError):
         repro.compile(library.gemm(4, 8, 4, in_dtype="u8"), "hvx",
-                      repro.CompileOptions(search={"strategy": "grid"}),
+                      repro.CompileOptions(search={"strategy": "beam"}),
                       cache=False)
 
 

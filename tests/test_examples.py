@@ -54,6 +54,6 @@ def test_warm_start_search_example(tmp_path):
     r = _run("examples/warm_start_search.py", timeout=1200,
              extra=("--store", str(tmp_path / "store")))
     assert r.returncode == 0, r.stderr[-2000:]
-    assert "winners pinned" in r.stdout
+    assert "3 units via serial" in r.stdout
     assert "seed(s) injected" in r.stdout
     assert "warm-start index:" in r.stdout

@@ -45,6 +45,110 @@ def test_attention_blocks_bounded():
     assert bq * bkv <= 256 * 1024
 
 
+# The blocks the chip runs: every tiler call that each benchmark cell's pass
+# makes (recorded while tracing the passes of benchmarks/chip/ with
+# jax.eval_shape), and the int8 blocks of the 12 GEMM/FC rows of the paper's
+# Table 2.  The values are the tiler's choices when the table was recorded;
+# a change that moves one changes what a cell runs on the chip.
+# (id, tiler, arguments, blocks); bf16 in and f32 out unless marked i8.
+PINNED_BLOCKS = [
+    ("stablelm-12b.prefill-4k/qkv", "gemm", (4096, 7680, 5120),
+     (4096, 384, 128)),
+    ("stablelm-12b.prefill-4k/out", "gemm", (4096, 5120, 5120),
+     (1024, 1024, 1024)),
+    ("stablelm-12b.prefill-4k/gate_up", "gemm", (4096, 13824, 5120),
+     (1024, 1536, 128)),
+    ("stablelm-12b.prefill-4k/down", "gemm", (4096, 5120, 13824),
+     (1024, 1024, 1536)),
+    ("stablelm-12b.prefill-4k/attention", "attention", (2048, 2048, 160),
+     (512, 512)),
+    ("stablelm-12b.decode-b32/qkv", "gemm", (32, 7680, 5120),
+     (32, 7680, 128)),
+    ("stablelm-12b.decode-b32/out", "gemm", (32, 5120, 5120),
+     (32, 5120, 128)),
+    ("stablelm-12b.decode-b32/gate_up", "gemm", (32, 13824, 5120),
+     (32, 13824, 128)),
+    ("stablelm-12b.decode-b32/down", "gemm", (32, 5120, 13824),
+     (32, 5120, 512)),
+    ("mamba2-2.7b.prefill-4k/in_proj", "gemm", (4096, 10576, 2560),
+     (128, 10624, 256)),
+    ("mamba2-2.7b.prefill-4k/out_proj", "gemm", (4096, 2560, 5120),
+     (4096, 512, 128)),
+    ("mamba2-2.7b.decode-b32/in_proj", "gemm", (32, 10576, 2560),
+     (32, 10624, 256)),
+    ("mamba2-2.7b.decode-b32/out_proj", "gemm", (32, 2560, 5120),
+     (32, 2560, 1024)),
+    ("granite-4.0-h-small.decode-b32/mamba_in", "gemm", (32, 16768, 4096),
+     (32, 16768, 128)),
+    ("granite-4.0-h-small.decode-b32/mamba_out", "gemm", (32, 4096, 8192),
+     (32, 4096, 512)),
+    ("granite-4.0-h-small.decode-b32/qkv", "gemm", (32, 6144, 4096),
+     (32, 6144, 512)),
+    ("granite-4.0-h-small.decode-b32/o", "gemm", (32, 4096, 4096),
+     (32, 4096, 1024)),
+    ("granite-4.0-h-small.decode-b32/shared_in", "gemm", (32, 3072, 4096),
+     (32, 3072, 512)),
+    ("granite-4.0-h-small.decode-b32/shared_out", "gemm", (32, 4096, 1536),
+     (32, 4096, 384)),
+    ("granite-4.0-h-small.decode-b32/experts_in", "grouped", (5, 1536, 4096),
+     (16, 1536, 1024)),
+    ("granite-4.0-h-small.decode-b32/experts_out", "grouped", (5, 4096, 768),
+     (16, 4096, 768)),
+    ("table2.gemm-int8/BERT-LG-GEMM1", "gemm_i8", (384, 4096, 1024),
+     (384, 4096, 1024)),
+    ("table2.gemm-int8/BERT-LG-GEMM2", "gemm_i8", (384, 1024, 4096),
+     (384, 1024, 4096)),
+    ("table2.gemm-int8/BERT-LG-ATN1-GEMM", "gemm_i8", (384, 64, 1024),
+     (384, 128, 1024)),
+    ("table2.gemm-int8/BERT-LG-ATN2-GEMM", "gemm_i8", (384, 384, 64),
+     (384, 384, 128)),
+    ("table2.gemm-int8/BERT-LG-ATN3-GEMM", "gemm_i8", (384, 64, 384),
+     (384, 128, 384)),
+    ("table2.gemm-int8/BERT-LG-ATN4-GEMM", "gemm_i8", (384, 1024, 1024),
+     (384, 1024, 1024)),
+    ("table2.gemm-int8/DLRM-FC1", "gemm_i8", (1, 367, 745), (8, 384, 768)),
+    ("table2.gemm-int8/DLRM-FC2", "gemm_i8", (1, 512, 367), (8, 512, 384)),
+    ("table2.gemm-int8/DLRM-FC3", "gemm_i8", (1, 256, 512), (8, 256, 512)),
+    ("table2.gemm-int8/DLRM-FC4", "gemm_i8", (1, 1, 256), (8, 128, 256)),
+    ("table2.gemm-int8/InceptionV3-FC1", "gemm_i8", (1, 1000, 2048),
+     (8, 1024, 2048)),
+    ("table2.gemm-int8/ResNet50-FC1", "gemm_i8", (1, 1000, 512),
+     (8, 1024, 512)),
+]
+
+
+@pytest.mark.parametrize("tiler,args,want",
+                         [p[1:] for p in PINNED_BLOCKS],
+                         ids=[p[0] for p in PINNED_BLOCKS])
+def test_tiler_blocks_pinned(tiler, args, want):
+    from repro.kernels.tiling import BF16_SUBLANE, grouped_gemm_blocks
+
+    if tiler == "attention":
+        bq, bkv = attention_blocks(*args)
+        assert (bq, bkv) == want
+        assert bq % 8 == 0 and bkv % 128 == 0
+        assert bq * bkv <= 256 * 1024
+        return
+    if tiler == "gemm_i8":
+        got = gemm_blocks(*args, in_dtype="i8", acc_dtype="i32")
+    elif tiler == "grouped":
+        got = grouped_gemm_blocks(*args)
+        assert got[0] % BF16_SUBLANE == 0
+    else:
+        got = gemm_blocks(*args, in_dtype="bf16")
+    assert got == want
+    _, n, k = args
+    bm, bn, bk = got
+    # VMEM fit: one copy of the (a, b, 4-byte out) windows within the
+    # tiler's third of the kernel's scoped limit (see core/targets.py)
+    in_bytes = 1 if tiler == "gemm_i8" else 2
+    bytes_ = (bm * bk + bk * bn) * in_bytes + bm * bn * 4
+    assert 3 * bytes_ <= TPU_V5E["vmem_limit_bytes"]
+    # MXU-aligned on n and k
+    assert bn % 128 == 0 and bk % 128 == 0
+    assert bm % 8 == 0
+
+
 # ---------------------------------------------------------------------------
 # matmul
 # ---------------------------------------------------------------------------

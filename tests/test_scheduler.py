@@ -8,6 +8,7 @@ pytest.importorskip("hypothesis")  # container may lack it; gate, don't fail
 from hypothesis import given, settings, strategies as st
 
 from repro.core import library, scheduler, targets
+from repro.core.covenant import CovenantError
 from repro.core.scheduler import (enumerate_tilings, plan_operands,
                                   validate_tiling)
 
@@ -56,9 +57,12 @@ def test_matmul_family_aliasing():
 
 
 def test_unsupported_capability_raises():
+    """The covenant stage rejects the pairing before any scheduling stage
+    runs, naming the missing capability."""
     acg = targets.example_acg()
     c = library.elementwise("ADD", 8, "f32")  # example ACG is integer-only
-    with pytest.raises(ValueError, match="no ACG node"):
+    with pytest.raises(CovenantError,
+                       match="supports capability 'ADD' at dtype f32"):
         scheduler.schedule(c, acg)
 
 

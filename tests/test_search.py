@@ -1,9 +1,8 @@
 """Search-based scheduling (core/search.py): a driver subsystem — never
-worse than the heuristic, functionally correct, deterministic per seed,
-strategy-pluggable, and materialised exclusively through the pipeline.
-PR 5 additions: the cost-bound-guided ``beam`` strategy, transfer-aware
-mutation, warm-starting from the artifact store, and the budget-matched
-acceptance comparisons."""
+worse than the heuristic, functionally correct, deterministic, and
+materialised exclusively through the pipeline.  The cost-bound-guided
+``beam`` strategy is checked against ``exhaustive`` (the optimum oracle)
+at an equal space cap, and warm-starting from the artifact store."""
 import dataclasses
 
 import numpy as np
@@ -12,7 +11,7 @@ import pytest
 import repro
 from repro.core import interp, library, targets
 from repro.core.search import (STRATEGIES, SearchOptions, SearchResult,
-                               _mutate, search_schedule)
+                               search_schedule)
 from repro.core.scheduler import schedule_space
 from repro.core.store import ArtifactStore
 
@@ -21,7 +20,7 @@ from repro.core.store import ArtifactStore
 def test_search_never_worse_and_correct(target, rng):
     acg = targets.get_target(target)
     cdlt = library.gemm(24, 32, 16, in_dtype="u8")
-    res = search_schedule(cdlt, acg, generations=4, population=10, seed=1)
+    res = search_schedule(cdlt, acg, generations=4, population=10)
     assert res.best_cycles <= res.heuristic_cycles
     # the search's heuristic baseline is exactly the driver's schedule
     assert res.heuristic_cycles == repro.compile(cdlt, target).cycles()
@@ -40,21 +39,20 @@ def test_search_improves_some_layer():
     gains = []
     for spec in library.PAPER_LAYERS[6:10]:  # DLRM FC stack (fast)
         res = search_schedule(spec.build(), acg, generations=5,
-                              population=12, seed=0)
+                              population=12)
         gains.append(res.gain)
     assert max(gains) > 1.0
     assert all(g >= 1.0 - 1e-9 for g in gains)
 
 
 def test_search_deterministic_trace():
-    """Same seed + same inputs -> identical trace, winner and evaluation
-    count (candidate generation and mutation draw from separate seeded
-    streams, so strategy interleaving cannot skew replay)."""
+    """Same inputs -> identical trace, winner and evaluation count (no
+    strategy draws a random number)."""
     acg = targets.get_target("hvx")
 
     def run():
         return search_schedule(library.gemm(24, 32, 16, in_dtype="u8"), acg,
-                               generations=4, population=10, seed=7)
+                               generations=4, population=10)
 
     a, b = run(), run()
     assert a.trace == b.trace
@@ -64,48 +62,23 @@ def test_search_deterministic_trace():
 
 
 def test_strategy_registry_complete_and_never_worse():
-    assert {"beam", "evolutionary", "random", "grid",
-            "exhaustive"} <= set(STRATEGIES)
+    assert set(STRATEGIES) == {"beam", "exhaustive"}
+    assert SearchOptions().strategy == "beam"
     acg = targets.get_target("hvx")
     results = {}
-    for strategy in ("beam", "evolutionary", "random", "grid", "exhaustive"):
+    for strategy in ("beam", "exhaustive"):
         res = search_schedule(library.gemm(8, 16, 12, in_dtype="u8"), acg,
                               strategy=strategy, generations=2,
-                              population=6, seed=0)
+                              population=6)
         assert res.best_cycles <= res.heuristic_cycles
         assert res.strategy == strategy
         results[strategy] = res
     # exhaustive visits the whole space: nothing beats its optimum
     assert all(results["exhaustive"].best_cycles <= r.best_cycles + 1e-9
                for r in results.values())
-    with pytest.raises(KeyError):
+    with pytest.raises(KeyError, match="beam.*exhaustive"):
         search_schedule(library.gemm(4, 8, 4, in_dtype="u8"), acg,
                         strategy="simulated-annealing")
-
-
-def test_mutation_moves_one_tile_to_neighbouring_divisor():
-    """The evolutionary mutation steps ONE loop's tile factor to an
-    adjacent divisor on its grid (or flips unroll) — not a +-k hop in a
-    flat enumeration index — and never leaves the valid region."""
-    import random
-    acg = targets.get_target("hvx")
-    space = schedule_space(library.gemm(24, 32, 16, in_dtype="u8"), acg)
-    base = tuple(sorted(space.tilings[0].items()))
-    rng = random.Random(3)
-    unrolls = (1, 2, 4, 8)
-    for _ in range(50):
-        new_t, new_u = _mutate((base, 4), space, unrolls, rng)
-        changed = [(v, f) for (v, f), (v0, f0) in zip(new_t, base) if f != f0]
-        if new_u != 4:
-            assert not changed              # unroll flip leaves tiling alone
-            assert new_u in unrolls
-        elif changed:
-            assert len(changed) == 1        # exactly one loop moved
-            var, factor = changed[0]
-            grid = space.divisors[var]
-            old = dict(base)[var]
-            assert abs(grid.index(factor) - grid.index(old)) == 1
-            assert space.valid(dict(new_t))
 
 
 def test_search_space_is_pipeline_fed():
@@ -127,23 +100,23 @@ def test_search_space_is_pipeline_fed():
 
 
 # ---------------------------------------------------------------------------
-# PR 5: determinism regression — every registered strategy, byte-identical
+# determinism regression — every strategy, byte-identical
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.search
 @pytest.mark.parametrize("strategy", sorted(STRATEGIES))
-def test_every_strategy_trace_byte_identical_per_seed(strategy):
-    """Same seed => byte-identical ``SearchResult.trace`` (repr compare),
-    same winner, same evaluation count — for EVERY registered strategy,
-    including the rng-free ``beam``.  This is the invariant that makes
-    store entries reproducible across processes and sweep backends."""
+def test_every_strategy_trace_byte_identical_across_runs(strategy):
+    """Same inputs => byte-identical ``SearchResult.trace`` (repr compare),
+    same winner, same evaluation count — for every strategy.  This is the
+    invariant that makes store entries reproducible across processes and
+    sweep backends."""
     acg = targets.get_target("dnnweaver")
 
     def run():
         return search_schedule(library.gemm(24, 32, 16, in_dtype="u8"), acg,
                                strategy=strategy, generations=3,
-                               population=8, seed=11, max_candidates=256)
+                               population=8, max_candidates=256)
 
     a, b = run(), run()
     assert repr(a.trace).encode() == repr(b.trace).encode()
@@ -153,7 +126,7 @@ def test_every_strategy_trace_byte_identical_per_seed(strategy):
 
 
 # ---------------------------------------------------------------------------
-# PR 5: SearchResult.gain degenerate edge
+# SearchResult.gain degenerate edge
 # ---------------------------------------------------------------------------
 
 
@@ -171,59 +144,7 @@ def test_gain_returns_zero_at_the_zero_cycle_optimum_edge():
 
 
 # ---------------------------------------------------------------------------
-# PR 5: transfer-aware mutation
-# ---------------------------------------------------------------------------
-
-
-def test_mutation_prefer_biases_but_stays_neighbouring():
-    """With a ``prefer`` pool, every tiling mutation moves one of the
-    preferred loops (still one divisor step, still valid); unroll flips
-    are unaffected."""
-    import random
-    acg = targets.get_target("hvx")
-    space = schedule_space(library.gemm(24, 32, 16, in_dtype="u8"), acg)
-    base = tuple(sorted(space.tilings[0].items()))
-    rng = random.Random(7)
-    moved = set()
-    for _ in range(60):
-        new_t, new_u = _mutate((base, 4), space, (1, 2, 4, 8), rng,
-                               prefer=("k",))
-        changed = [(v, f) for (v, f), (v0, f0) in zip(new_t, base)
-                   if f != f0]
-        if new_u == 4 and changed:
-            assert len(changed) == 1
-            moved.add(changed[0][0])
-    assert moved == {"k"}
-
-
-def test_hot_vars_only_for_transfer_dominated_reports():
-    """_hot_vars consults the evaluated parent's CostReport: a compute-
-    dominated parent gets no bias, a transfer-dominated one gets the
-    dominant operand's loops."""
-    from repro.core.cost import CostReport
-    from repro.core.search import _hot_vars
-    acg = targets.get_target("hvx")
-    space = schedule_space(library.gemm(24, 32, 16, in_dtype="u8"), acg)
-    pt = (tuple(sorted(space.tilings[0].items())), 4)
-
-    def fake_eval(reports):
-        def evaluate(p):
-            return 0.0
-        evaluate.reports = reports
-        return evaluate
-
-    mem_heavy = CostReport(cycles=10, compute_cycles=1, transfer_cycles=9,
-                           overhead_cycles=0, compute_invocations=1,
-                           transfer_mnemonics=9)
-    cpu_heavy = CostReport(cycles=10, compute_cycles=9, transfer_cycles=1,
-                           overhead_cycles=0, compute_invocations=9,
-                           transfer_mnemonics=1)
-    assert _hot_vars(space, pt, fake_eval({pt: mem_heavy}), {})
-    assert _hot_vars(space, pt, fake_eval({pt: cpu_heavy}), {}) == []
-
-
-# ---------------------------------------------------------------------------
-# PR 5: warm-starting from the artifact store
+# warm-starting from the artifact store
 # ---------------------------------------------------------------------------
 
 
@@ -235,13 +156,12 @@ def test_warm_start_seeds_from_store_and_never_hurts(tmp_path):
     repro.clear_cache()
     store = ArtifactStore(str(tmp_path / "store"))
     pre = SearchOptions(strategy="beam", generations=3, population=8,
-                        seed=0, max_candidates=256)
+                        max_candidates=256)
     repro.compile("DLRM-FC2", "hvx",
                   repro.CompileOptions(search=pre, store=store))
 
-    warm = SearchOptions(strategy="evolutionary", generations=3,
-                         population=8, seed=9, max_candidates=256,
-                         warm_start=True)
+    warm = SearchOptions(strategy="beam", generations=3, population=8,
+                         max_candidates=256, warm_start=True)
     cold = dataclasses.replace(warm, warm_start=False)
     a_w = repro.compile("DLRM-FC2", "hvx",
                         repro.CompileOptions(search=warm, store=store))
@@ -267,7 +187,7 @@ def test_warm_start_entry_roundtrips_seeded_and_sig(tmp_path):
     repro.clear_cache()
     store = ArtifactStore(str(tmp_path / "store"))
     sopts = SearchOptions(strategy="beam", generations=2, population=6,
-                          seed=0, max_candidates=128)
+                          max_candidates=128)
     art = repro.compile("DLRM-FC3", "hvx",
                         repro.CompileOptions(search=sopts, store=store))
     sig = art.search.space_sig
@@ -280,82 +200,57 @@ def test_warm_start_entry_roundtrips_seeded_and_sig(tmp_path):
     assert warm.search.seeded == art.search.seeded
 
 
-# ---------------------------------------------------------------------------
-# PR 5: budget-matched acceptance — beam vs evolutionary
+# acceptance — beam at a 16-evaluation budget against the exhaustive oracle
 # ---------------------------------------------------------------------------
 
 FAST_LAYERS = ["DLRM-FC1", "DLRM-FC2", "DLRM-FC3"]
 
 
+def _beam_and_oracle(cdlt, acg):
+    """beam under a 16-evaluation budget, and exhaustive over the same
+    512-tiling cap (every tiling at every unroll factor)."""
+    rb = search_schedule(cdlt, acg, strategy="beam", generations=2,
+                         population=8, max_candidates=512)
+    rx = search_schedule(cdlt, acg, strategy="exhaustive",
+                         max_candidates=512)
+    return rb, rx
+
+
 @pytest.mark.search
 @pytest.mark.parametrize("target", ["hvx", "dnnweaver"])
 def test_beam_budget_matched_on_dlrm_subset(target):
-    """The CI-sized acceptance: on the DLRM subset, beam at an equal
-    evaluation budget finds cycles <= evolutionary's."""
+    """The CI-sized acceptance: on the DLRM subset, beam's 16 evaluations
+    find cycles <= the optimum of exhaustive's whole capped space."""
     acg = targets.get_target(target)
     for key in FAST_LAYERS:
-        cdlt = library.paper_layer(key)
-        rb = search_schedule(cdlt, acg, strategy="beam", generations=2,
-                             population=8, seed=0, max_candidates=512)
-        re_ = search_schedule(cdlt, acg, strategy="evolutionary",
-                              generations=2, population=8, seed=0,
-                              max_candidates=512)
-        assert rb.evaluated <= 16           # the shared budget
-        assert rb.best_cycles <= re_.best_cycles + 1e-9, (key, target)
+        rb, rx = _beam_and_oracle(library.paper_layer(key), acg)
+        assert rb.evaluated <= 16           # the budget
+        assert rx.evaluated > rb.evaluated  # the oracle saw more points
+        assert rb.best_cycles <= rx.best_cycles + 1e-9, (key, target)
 
 
 @pytest.mark.slow
 @pytest.mark.search
 @pytest.mark.parametrize("target", ["hvx", "dnnweaver"])
-def test_beam_matches_or_beats_evolutionary_every_paper_layer(target):
-    """Acceptance: on every Table-2 layer x both eval targets, beam under
-    an equal ``evaluate()`` budget matches or beats evolutionary."""
+def test_beam_matches_or_beats_exhaustive_every_paper_layer(target):
+    """Acceptance: on every Table-2 layer x both eval targets, beam's 16
+    evaluations match or beat exhaustive over the same tiling cap (beam
+    builds its candidates from prefixes, so it can reach points beyond
+    the cap)."""
     acg = targets.get_target(target)
-    budget = 16
     for spec in library.PAPER_LAYERS:
-        rb = search_schedule(spec.build(), acg, strategy="beam",
-                             generations=2, population=8, seed=0,
-                             max_candidates=512)
-        re_ = search_schedule(spec.build(), acg, strategy="evolutionary",
-                              generations=2, population=8, seed=0,
-                              max_candidates=512)
-        assert rb.evaluated <= budget
-        assert rb.best_cycles <= re_.best_cycles + 1e-9, (
-            spec.key, target, rb.best_cycles, re_.best_cycles)
-
-
-@pytest.mark.slow
-@pytest.mark.search
-def test_warm_started_evolutionary_converges_in_fewer_evaluations(tmp_path):
-    """Acceptance: with the store carrying a previous search's best point,
-    warm-started evolutionary converges earlier than cold — strictly
-    shorter trace (patience cuts it at the plateau) and strictly fewer
-    evaluations, at an equal-or-better final schedule."""
-    repro.clear_cache()
-    store = ArtifactStore(str(tmp_path / "store"))
-    pre = SearchOptions(strategy="beam", generations=4, population=10,
-                        seed=0, max_candidates=256)
-    repro.compile("InceptionV3-FC1", "hvx",
-                  repro.CompileOptions(search=pre, store=store))
-    base = SearchOptions(strategy="evolutionary", generations=10,
-                         population=10, seed=3, max_candidates=256,
-                         patience=2)
-    warm = dataclasses.replace(base, warm_start=True)
-    a_w = repro.compile("InceptionV3-FC1", "hvx",
-                        repro.CompileOptions(search=warm, store=store))
-    a_c = repro.compile("InceptionV3-FC1", "hvx",
-                        repro.CompileOptions(search=base, store=store))
-    assert len(a_w.search.trace) < len(a_c.search.trace)
-    assert a_w.search.evaluated < a_c.search.evaluated
-    assert a_w.search.best_cycles <= a_c.search.best_cycles + 1e-9
+        rb, rx = _beam_and_oracle(spec.build(), acg)
+        assert rb.evaluated <= 16
+        assert rb.best_cycles <= rx.best_cycles + 1e-9, (
+            spec.key, target, rb.best_cycles, rx.best_cycles)
 
 
 def test_driver_search_option_every_paper_layer_both_targets():
     """Acceptance: CompileOptions(search=...) returns an artifact at least
     as good as the heuristic for every paper layer on both targets, with
     the search trace attached, under the same content-addressed scheme."""
-    sopts = repro.SearchOptions(strategy="random", generations=1,
-                                population=4, seed=0, max_candidates=128)
+    sopts = repro.SearchOptions(strategy="beam", generations=1,
+                                population=4, max_candidates=128)
     for target in ("hvx", "dnnweaver"):
         for spec in library.PAPER_LAYERS:
             heur = repro.compile(spec, target)

@@ -459,17 +459,3 @@ def test_covenant_clean_on_every_bundled_target():
         assert validate_acg(acg, raise_on_error=False) == []
         assert check_covenant(library.gemm(8, 16, 12, in_dtype="u8"), acg,
                               raise_on_error=False) == []
-
-
-def test_covenant_check_can_be_disabled():
-    """check_covenant=False restores the old late-failure behaviour (and a
-    distinct cache key), for callers who want raw pipeline errors."""
-    with pytest.raises(ValueError) as ei:
-        repro.compile(_codelet_with_capability("FFT"), "hvx",
-                      repro.CompileOptions(check_covenant=False),
-                      cache=False)
-    assert not isinstance(ei.value, CovenantError)  # the deep error again
-    art = repro.compile(_codelet_with_capability("ADD"), "hvx",
-                        repro.CompileOptions(check_covenant=False),
-                        cache=False)
-    assert art.cycles() > 0

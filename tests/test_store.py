@@ -55,8 +55,7 @@ def test_warm_restore_runs_zero_stages_and_replays_identically(store):
 
 def test_searched_artifact_roundtrips_with_trace(store):
     opts = repro.CompileOptions(
-        store=store, search=repro.SearchOptions(generations=3, population=8,
-                                                seed=0))
+        store=store, search=repro.SearchOptions(generations=3, population=8))
     a1 = repro.compile(_gemm(), "hvx", opts)
     assert a1.search is not None and a1.search.trace
     repro.clear_cache()
@@ -380,7 +379,7 @@ def test_own_entries_still_evict_under_size_pressure(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# locks, claims, journal, gc
+# locks, journal, gc
 # ---------------------------------------------------------------------------
 
 
@@ -399,22 +398,6 @@ def test_filelock_excludes_and_breaks_stale(tmp_path):
     c = FileLock(path, stale_timeout=60)
     assert c.acquire(timeout=1.0)                    # stale lock broken
     c.release()
-
-
-def test_claims_are_exclusive_released_and_reclaimed(tmp_path):
-    st = ArtifactStore(str(tmp_path))
-    key = "c" * 64
-    assert st.claim("s1", key, "w1")
-    assert not st.claim("s1", key, "w2")             # held by w1
-    st.release_claim("s1", key, "w2")                # not w2's to release
-    assert not st.claim("s1", key, "w2")
-    st.release_claim("s1", key, "w1")
-    assert st.claim("s1", key, "w2")                 # properly released
-    path = st._claim_path("s1", key)
-    past = os.stat(path).st_mtime - 3600
-    os.utime(path, (past, past))
-    assert st.claim("s1", key, "w3", stale_timeout=60)  # stale: reclaimed
-    assert st.stats["reclaims"] == 1
 
 
 def test_journal_is_monotonic_and_readable(tmp_path):
@@ -436,13 +419,13 @@ def test_gc_by_age_size_and_stale_claims(tmp_path):
         st.put(key, {"reports": {}, "pack": True})
     past = os.stat(st._path(old)).st_mtime - 7200
     os.utime(st._path(old), (past, past))
-    st.claim("s2", "f" * 64, "dead-worker")
-    cpath = st._claim_path("s2", "f" * 64)
-    os.utime(cpath, (past, past))
+    st.journal("s2").append({"event": "compiled", "key": old})
+    sweep_dir = st.sweep_dir("s2")
+    os.utime(sweep_dir, (past, past))
     out = st.gc(max_age=3600)
-    assert out["aged"] == 1 and out["claims_reaped"] == 1
+    assert out["aged"] == 1 and out["sweeps_reaped"] == 1
     assert st.keys() == [young]
-    assert not os.path.exists(cpath)
+    assert not os.path.exists(sweep_dir)
     # size-driven gc: shrink the budget so the survivor must go too
     out = st.gc(max_bytes=0)
     assert out["evicted"] >= 0  # keep-newest still protects one entry
@@ -488,7 +471,7 @@ def test_second_process_warm_sweep_is_store_hits_only(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# PR 5: warm-start index + race pins
+# warm-start index
 # ---------------------------------------------------------------------------
 
 
@@ -522,7 +505,7 @@ def test_warm_start_index_prefers_exact_space_signature(store):
     from repro.core.search import SearchOptions
 
     sopts = SearchOptions(strategy="beam", generations=2, population=6,
-                          seed=0, max_candidates=128)
+                          max_candidates=128)
     art = repro.compile("DLRM-FC4", "hvx",
                         repro.CompileOptions(search=sopts, store=store))
     sig = art.search.space_sig
@@ -532,39 +515,6 @@ def test_warm_start_index_prefers_exact_space_signature(store):
     assert space.signature() == sig
     seeds = idx.seeds(space, (1, 2, 4, 8), limit=1)
     assert seeds and seeds[0][0] == art.search.point["tiling"]
-
-
-def test_pins_roundtrip_atomically_and_clear(store):
-    rec = {"layer": "L", "target": "hvx", "key": "a" * 64,
-           "strategy": "beam", "cycles": 123.0,
-           "point": {"tiling": {"m": 4}, "unroll_factor": 2}}
-    name = store.pin_name("L", "hvx@pe=8x8")
-    assert "/" not in name
-    store.pin(name, rec)
-    got = store.load_pin(name)
-    assert got is not None and got["cycles"] == 123.0
-    assert got["pin"] == name
-    assert name in store.pins()
-    assert store.load_pin("nope") is None
-    store.clear()
-    assert store.pins() == {}
-
-
-def test_warm_start_index_consumes_pins(store):
-    from repro.core.scheduler import schedule_space
-    from repro.core.store import WarmStartIndex
-
-    acg = repro.targets.get("hvx")
-    space = schedule_space(library.paper_layer("DLRM-FC4"), acg)
-    tiling = space.tilings[0]
-    store.pin(store.pin_name("DLRM-FC4", "hvx"),
-              {"layer": "DLRM-FC4", "target": "hvx", "key": "0" * 64,
-               "strategy": "beam", "cycles": 1.0,
-               "space_sig": space.signature(),
-               "point": {"tiling": tiling, "unroll_factor": 4}})
-    idx = WarmStartIndex.from_store(store)
-    seeds = idx.seeds(space, (1, 2, 4, 8), limit=2)
-    assert (tiling, 4) in [(t, u) for t, u in seeds]
 
 
 def test_warm_start_index_rejects_foreign_shapes(store):

@@ -1,8 +1,7 @@
 """Multi-process sweep coordinator (core/sweep.py): deterministic plan
 expansion and partitioning, dedup against the shared artifact store,
-claim-based external workers with stale-claim reclaim, report merge
-identity vs sequential ``compile_many``, and the exactly-once journal
-contract across worker processes."""
+report merge identity vs sequential ``compile_many``, and the
+exactly-once journal contract across worker processes."""
 import json
 import os
 import subprocess
@@ -14,7 +13,7 @@ import repro
 from repro.core import sweep as sweep_mod
 from repro.core.store import ArtifactStore
 from repro.core.sweep import (SweepReport, UnitResult, expand_plan,
-                              partition, plan_id, run_external_worker)
+                              partition, plan_id)
 
 pytestmark = pytest.mark.sweep
 
@@ -62,16 +61,14 @@ def test_partition_is_deterministic_and_complete():
 
 
 def test_search_axis_creates_distinct_units():
-    searches = [None, repro.SearchOptions(generations=2, population=4,
-                                          seed=0)]
+    searches = [None, repro.SearchOptions(generations=2, population=4)]
     units = expand_plan(["DLRM-FC4"], ["hvx"], searches=searches)
     assert len(units) == 2
-    assert {u.opt for u in units} == \
-        {"heuristic", "search:evolutionary@g2p4s0"}
+    assert {u.opt for u in units} == {"heuristic", "search:beam@g2p4"}
 
 
 def test_workunit_json_roundtrip():
-    searches = [repro.SearchOptions(generations=2, population=4, seed=3)]
+    searches = [repro.SearchOptions(generations=2, population=4)]
     for unit in expand_plan(LAYERS[:1], VARIANTS, searches=searches):
         back = sweep_mod.WorkUnit.from_json(
             json.loads(json.dumps(unit.to_json())))
@@ -154,83 +151,6 @@ def test_warm_sweep_is_all_dedup_with_zero_stages(store):
 
 
 # ---------------------------------------------------------------------------
-# external workers: claims + stale-claim reclaim
-# ---------------------------------------------------------------------------
-
-
-def test_live_claim_is_respected_stale_claim_is_reclaimed(store):
-    units = expand_plan(["DLRM-FC4"], ["hvx", "dnnweaver"])
-    sid = plan_id(units)
-    # another (live) worker holds unit 0: we must skip it
-    # (drain_timeout=0: single pass — don't wait out the live claim)
-    assert store.claim(sid, units[0].key, "other-worker")
-    rep = run_external_worker(units, store, "me", sweep_id=sid,
-                              stale_claim_timeout=600, drain_timeout=0)
-    by_key = {r.key: r for r in rep.results}
-    assert by_key[units[0].key].status == "skipped"
-    assert by_key[units[1].key].status == "ok"
-    # the holder crashed: its claim goes stale and is reclaimed
-    claim = store._claim_path(sid, units[0].key)
-    past = os.stat(claim).st_mtime - 3600
-    os.utime(claim, (past, past))
-    rep2 = run_external_worker(units, store, "me", sweep_id=sid,
-                               stale_claim_timeout=60)
-    by_key = {r.key: r for r in rep2.results}
-    assert by_key[units[0].key].status == "ok"
-    assert by_key[units[0].key].source == "compiled"
-    assert store.stats["reclaims"] == 1
-    # merged fleet view: every unit done exactly once
-    merged = SweepReport.merge([rep, rep2])
-    assert all(r.status == "ok" for r in merged.results)
-    assert set(store.journal(sid).compile_counts().values()) == {1}
-
-
-def test_claim_heartbeat_keeps_long_compiles_alive(tmp_path):
-    """A held claim is refreshed while its unit compiles, so a slow unit
-    is never mistaken for a crashed worker's and double-compiled."""
-    import time
-    path = tmp_path / "unit.claim"
-    path.write_text("{}")
-    with sweep_mod._ClaimHeartbeat(str(path), interval=0.05):
-        past = os.stat(path).st_mtime - 3600
-        os.utime(path, (past, past))        # simulate ageing toward stale
-        time.sleep(0.3)                     # ... but the heartbeat beats
-        assert time.time() - os.stat(path).st_mtime < 10
-    # once the worker stops (crash/exit), the claim ages out normally
-    past = os.stat(path).st_mtime - 3600
-    os.utime(path, (past, past))
-    time.sleep(0.15)
-    assert time.time() - os.stat(path).st_mtime >= 3600 - 60
-
-
-def test_survivor_drains_units_of_a_worker_that_crashed_mid_claim(store):
-    """The last live worker must not walk past a held claim and exit: it
-    re-visits held units until the holder finishes (store hit) or its
-    claim goes stale — here the 'holder' is dead from the start, so the
-    survivor waits out the stale timeout and reclaims."""
-    units = expand_plan(["DLRM-FC4"], ["hvx"])
-    sid = plan_id(units)
-    assert store.claim(sid, units[0].key, "crashed-worker")
-    rep = run_external_worker(units, store, "survivor", sweep_id=sid,
-                              stale_claim_timeout=1.0, drain_timeout=30)
-    by_key = {r.key: r for r in rep.results}
-    assert by_key[units[0].key].status == "ok"       # drained, not skipped
-    assert by_key[units[0].key].source == "compiled"
-    assert store.stats["reclaims"] == 1
-
-
-def test_two_external_workers_drain_the_plan_without_double_work(store):
-    units = expand_plan(LAYERS, VARIANTS[:1])
-    sid = plan_id(units)
-    reps = [run_external_worker(units, store, w, sweep_id=sid)
-            for w in ("w-a", "w-b")]
-    merged = SweepReport.merge(reps)
-    assert merged.counts()["ok"] == len(units)
-    counts = store.journal(sid).compile_counts()
-    assert len(counts) == len(units) and set(counts.values()) == {1}
-
-
-# ---------------------------------------------------------------------------
 # process backend + compile_many(parallel=)
 # ---------------------------------------------------------------------------
 
@@ -303,59 +223,20 @@ def test_cli_expect_store_hits_fails_cold(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# PR 5: strategy racing + cross-backend determinism
+# search axis: option round trips + cross-backend determinism
 # ---------------------------------------------------------------------------
 
-RACE_SEARCHES = [
+SEARCHES = [
     repro.SearchOptions(strategy="beam", generations=2, population=6,
-                        seed=0, max_candidates=128),
-    repro.SearchOptions(strategy="evolutionary", generations=2,
-                        population=6, seed=0, max_candidates=128),
+                        max_candidates=128),
+    repro.SearchOptions(strategy="exhaustive", max_candidates=128),
 ]
-
-
-@pytest.mark.search
-def test_race_pins_winner_per_layer_and_journals_exactly_once(store):
-    layers = ["DLRM-FC3", "DLRM-FC4"]
-    report = repro.sweep(layers, ["hvx"], store=store,
-                         searches=RACE_SEARCHES, race=True)
-    assert report.counts()["ok"] == 4          # 2 layers x 2 strategies
-    assert len(report.pins) == len(layers)
-    counts = store.journal(report.sweep_id).compile_counts()
-    assert len(counts) == 4                    # one per (layer, strategy)
-    assert set(counts.values()) == {1}         # ...compiled exactly once
-    by_layer = {r.layer: [] for r in report.ok}
-    for r in report.ok:
-        by_layer[r.layer].append(r)
-    for pin in report.pins:
-        assert pin["cycles"] == min(r.cycles for r in by_layer[pin["layer"]])
-        assert pin["strategy"] in ("beam", "evolutionary")
-        assert sorted(pin["raced"]) == pin["raced"] and len(pin["raced"]) == 2
-        assert store.load_pin(store.pin_name(pin["layer"], "hvx")) is not None
-    assert "winner" in report.race_table()
-
-    # a warm re-race changes nothing: all dedup, same winners, still once
-    warm = repro.sweep(layers, ["hvx"], store=store,
-                       searches=RACE_SEARCHES, race=True)
-    assert warm.counts()["dedup"] == 4
-    assert [p["key"] for p in warm.pins] == [p["key"] for p in report.pins]
-    counts = store.journal(report.sweep_id).compile_counts()
-    assert set(counts.values()) == {1}
-
-
-def test_race_requires_store_and_two_strategies(store):
-    with pytest.raises(ValueError, match="ArtifactStore"):
-        repro.sweep(["DLRM-FC4"], ["hvx"], searches=RACE_SEARCHES,
-                    race=True, store=None)
-    with pytest.raises(ValueError, match="two"):
-        repro.sweep(["DLRM-FC4"], ["hvx"], store=store, race=True,
-                    searches=[RACE_SEARCHES[0]])
 
 
 def test_search_options_json_roundtrip_with_pr5_fields():
     from repro.core.sweep import options_from_json, options_to_json
     sopts = repro.SearchOptions(strategy="beam", beam_width=5,
-                                warm_start=True, patience=3)
+                                warm_start=True)
     opts = repro.CompileOptions(search=sopts)
     rt = options_from_json(json.loads(json.dumps(options_to_json(opts))))
     assert rt.search == sopts
@@ -364,8 +245,8 @@ def test_search_options_json_roundtrip_with_pr5_fields():
 
 @pytest.mark.search
 def test_search_traces_byte_identical_across_fork_and_spawn(tmp_path):
-    """Same plan, same seed, different worker start methods: the stored
-    search digests (trace, winner, cycles) must be byte-identical — the
+    """Same plan, different worker start methods: the stored search
+    digests (trace, winner, cycles) must be byte-identical — the
     determinism contract across sweep backends."""
     import multiprocessing as mp
 
@@ -378,7 +259,7 @@ def test_search_traces_byte_identical_across_fork_and_spawn(tmp_path):
         repro.clear_cache()
         st = ArtifactStore(str(tmp_path / method))
         report = repro.sweep(["DLRM-FC4"], ["hvx"], store=st, workers=2,
-                             searches=RACE_SEARCHES, backend="process",
+                             searches=SEARCHES, backend="process",
                              mp_start=method)
         assert report.counts()["ok"] == 2, report.summary()
         entries = {}
@@ -391,62 +272,11 @@ def test_search_traces_byte_identical_across_fork_and_spawn(tmp_path):
     repro.clear_cache()
 
 
-@pytest.mark.search
-def test_cli_race_prints_winners_and_asserts_unique(tmp_path):
-    env = dict(os.environ, PYTHONPATH="src",
-               REPRO_CACHE_DIR=str(tmp_path / "store"))
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.sweep",
-         "--layers", "DLRM-FC4", "--targets", "hvx",
-         "--search", "strategy=beam,generations=2,population=6,seed=0,"
-                     "max_candidates=128",
-         "--search", "strategy=evolutionary,generations=2,population=6,"
-                     "seed=0,max_candidates=128",
-         "--race", "--assert-unique-compiles"],
-        capture_output=True, text=True, env=env, cwd=ROOT, timeout=600)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "winner" in r.stdout
-    assert "compiled exactly once" in r.stdout
-
-
-def test_cli_race_needs_two_searches(tmp_path):
-    env = dict(os.environ, PYTHONPATH="src",
-               REPRO_CACHE_DIR=str(tmp_path / "store"))
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.sweep", "--layers", "DLRM-FC4",
-         "--targets", "hvx", "--search", "beam", "--race"],
-        capture_output=True, text=True, env=env, cwd=ROOT, timeout=600)
-    assert r.returncode == 2
-    assert "two" in r.stderr
-
-
-def test_race_pins_survivor_when_rival_strategy_fails(store):
-    """A rival strategy's unit failing must not cost the (layer, target)
-    its pin: the surviving strategy's best result is pinned."""
-    import dataclasses
-
-    from repro.core.sweep import _pin_race_winners
-
-    units = expand_plan(["DLRM-FC4"], ["hvx"], searches=RACE_SEARCHES)
-    ok_unit, failed_unit = units
-    art = repro.compile("DLRM-FC4", "hvx",
-                        dataclasses.replace(ok_unit.options, store=store))
-    report = SweepReport(sweep_id="x", results=[
-        UnitResult(key=ok_unit.key, layer="DLRM-FC4", target="hvx",
-                   opt=ok_unit.opt, status="ok", source="compiled",
-                   cycles=art.cycles()),
-        UnitResult(key=failed_unit.key, layer="DLRM-FC4", target="hvx",
-                   opt=failed_unit.opt, status="failed", error="boom"),
-    ])
-    pins = _pin_race_winners(units, report, store, None)
-    assert len(pins) == 1
-    assert pins[0]["key"] == ok_unit.key
-
-
 def test_cli_rejects_malformed_search_spec(tmp_path):
     env = dict(os.environ, PYTHONPATH="src",
                REPRO_CACHE_DIR=str(tmp_path / "store"))
-    for bad in ("bem", "generations=lots"):
+    for bad in ("bem", "generations=lots", "strategy=evolutionary",
+                "seed=0"):
         r = subprocess.run(
             [sys.executable, "-m", "repro.sweep", "--layers", "DLRM-FC4",
              "--targets", "hvx", "--search", bad],
