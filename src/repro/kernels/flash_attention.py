@@ -3,7 +3,8 @@
 Online-softmax over kv blocks with running (max, sum) scratch in VMEM.
 Supports causal masking, sliding windows (gemma3's 5:1 local layers) and a
 single-query decode variant, whose online softmax walks the kv blocks of a
-cache read row-major or with its slots on the lanes.
+cache read row-major or with its slots on the lanes, each row's walk stopping
+at its length.
 
 Block geometry again comes from the Covenant tiler
 (``tiling.attention_blocks``): the QK^T GEMM's Algorithm-1 tiling is the
@@ -112,13 +113,35 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     )(q, k, v)
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
+def _last_block(kv_len, block_kv: int):
+    """The last K/V block that holds a valid slot; block 0 for a length of
+    0.  The decode walk's ``index_map`` and ``decode_blocks_read`` both
+    read it."""
+    return jnp.maximum(kv_len - 1, 0) // block_kv
+
+
+def decode_blocks_read(kv_len, s: int, block_kv: int) -> tuple[int, int]:
+    """(K/V blocks the decode walk fetches, blocks of its grid) for the
+    (BKV,) lengths ``kv_len`` over ``s`` slots: each row fetches its
+    blocks up to its last valid one, and at least one."""
+    n_blocks = -(-s // block_kv)
+    last = _last_block(jnp.asarray(kv_len, jnp.int32), block_kv)
+    read = jnp.sum(jnp.minimum(last + 1, n_blocks))
+    return int(read), len(kv_len) * n_blocks
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, scale: float, block_kv: int,
                    slots_minor: bool):
     """One (kv head, kv block) step of the online softmax.  K/V blocks are
     (bkv, d) row-major, or (d, bkv) with the slots on the lanes when
-    ``slots_minor``: only the two contractions differ."""
+    ``slots_minor``: only the two contractions differ.  The (BKV,) lengths
+    are scalar-prefetched into ``len_ref``; a step whose block starts at
+    or past its row's length computes nothing (its K/V ``index_map``
+    repeats the last valid block, so nothing is fetched either), and a
+    length of 0 gives 0."""
     b, kj = pl.program_id(0), pl.program_id(1)
+    n = len_ref[b]
 
     @pl.when(kj == 0)
     def _init():
@@ -126,26 +149,29 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)           # (Hg, d) — grouped q heads
-    k = k_ref[0].astype(jnp.float32)
-    s = jnp.dot(q, k if slots_minor else k.T,
-                preferred_element_type=jnp.float32) * scale    # (Hg, bkv)
-    kpos = kj * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kpos < len_ref[b]
-    s = jnp.where(mask, s, NEG_INF)
+    @pl.when(kj * block_kv < n)
+    def _step():
+        q = q_ref[0].astype(jnp.float32)       # (Hg, d) — grouped q heads
+        k = k_ref[0].astype(jnp.float32)
+        s = jnp.dot(q, k if slots_minor else k.T,
+                    preferred_element_type=jnp.float32) * scale  # (Hg, bkv)
+        kpos = kj * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                        1)
+        mask = kpos < n
+        s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0].astype(jnp.float32)
-    # p (Hg, bkv) against v over the slots: v's axis 0, or its lanes
-    pv = jax.lax.dot_general(p, v, (((1,), (1 if slots_minor else 0,)),
-                                    ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = m_new
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[0].astype(jnp.float32)
+        # p (Hg, bkv) against v over the slots: v's axis 0, or its lanes
+        pv = jax.lax.dot_general(p, v, (((1,), (1 if slots_minor else 0,)),
+                                        ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
 
     @pl.when(kj == pl.num_programs(1) - 1)
     def _flush():
@@ -164,7 +190,10 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 
     q: (BKV, Hg, D) — one query block per kv head (Hg = q heads per kv
     head); k, v: (BKV, S, D), or (BKV, D, S) when ``slots_minor``;
-    kv_len: (BKV,) valid lengths.
+    kv_len: (BKV,) valid lengths, scalar-prefetched.  Each row's walk
+    stops at its last valid block: the blocks past it are neither read nor
+    computed (``decode_blocks_read`` counts the ones that are), and a
+    length of 0 gives 0.
     """
     bkv, hg, d = q.shape
     at = 2 if slots_minor else 1           # the slots' axis of k and v
@@ -177,37 +206,39 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         with jax.named_scope("pad"):
             k = jnp.pad(k, pad)
             v = jnp.pad(v, pad)
-    if slots_minor:
-        kv_spec = pl.BlockSpec((1, d, block_kv), lambda b, j: (b, 0, j))
-    else:
-        kv_spec = pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0))
+
+    def kv_index(b, j, lens):
+        # past the last valid block, repeat it: the pipeline fetches nothing
+        j = jnp.minimum(j, _last_block(lens[b], block_kv))
+        return (b, 0, j) if slots_minor else (b, j, 0)
+
+    kv_spec = pl.BlockSpec((1, d, block_kv) if slots_minor
+                           else (1, block_kv, d), kv_index)
+    row_spec = pl.BlockSpec((1, hg, d), lambda b, j, lens: (b, 0, 0))
     kernel = functools.partial(_decode_kernel, scale=scale, block_kv=block_kv,
                                slots_minor=slots_minor)
     return pl.pallas_call(
         kernel,
         name="flash_decode",
-        grid=(bkv, s_pad // block_kv),
-        in_specs=[
-            pl.BlockSpec((1, hg, d), lambda b, j: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
-            # all (BKV,) lengths sit in SMEM; the kernel indexes its row
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, hg, d), lambda b, j: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bkv, s_pad // block_kv),
+            in_specs=[row_spec, kv_spec, kv_spec],
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hg, 1), jnp.float32),
+                pltpu.VMEM((hg, 1), jnp.float32),
+                pltpu.VMEM((hg, d), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((hg, 1), jnp.float32),
-            pltpu.VMEM((hg, 1), jnp.float32),
-            pltpu.VMEM((hg, d), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, kv_len)
+    )(kv_len, q, k, v)
 
 
-__all__ = ["flash_attention", "flash_attention_bwd",
+__all__ = ["decode_blocks_read", "flash_attention", "flash_attention_bwd",
            "flash_attention_fwd_lse", "flash_decode"]
 
 

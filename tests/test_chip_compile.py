@@ -162,9 +162,11 @@ def test_flash_decode_reads_the_cache_as_held(compile_for_chip, d):
                                                          interpret=False),
         ((32, 32, d), bf), (cache, bf), (cache, bf), ((32,), jnp.int32))
     assert kernels(text) == {"flash_decode"}
+    # operands: the scalar-prefetched lengths, q, k, v
     constraints = re.search(r"flash_decode[.\d]* = .*?operand_layout_"
-                            r"constraints=\{([^}]*\}[^}]*\}[^}]*\})", text)
-    assert constraints.group(1).split(", ")[1:] == [HELD_AS[d]] * 2
+                            r"constraints=\{([^}]*\}[^}]*\}[^}]*\}"
+                            r"[^}]*\})", text)
+    assert constraints.group(1).split(", ")[2:] == [HELD_AS[d]] * 2
     assert not copies(text, 32 * 8 * 8192 * d)
 
 
@@ -191,7 +193,7 @@ def test_flash_decode_shares_the_copy_back_of_a_written_cache(
     assert len(copies(text, 32 * 8 * 8192 * 160)) == 4
     call = re.search(r"flash_decode[.\d]* = [^\n]*?custom-call\(([^)]*)\)",
                      text)
-    k_op, v_op = call.group(1).split(", ")[1:3]
+    k_op, v_op = call.group(1).split(", ")[2:4]
     # the entry computation comes last; its root returns the caches
     returned = re.findall(r"ROOT [^\n]* tuple\(([^)]*)\)",
                           text)[-1].split(", ")

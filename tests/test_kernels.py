@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.core.targets import TPU_V5E
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import decode_blocks_read
 from repro.kernels.tiling import attention_blocks, gemm_blocks
 
 rng = np.random.default_rng(7)
@@ -259,7 +260,8 @@ def test_flash_decode_matches_ref(case):
     kv_len = jnp.asarray(lens)
     call = functools.partial(ops.covenant_decode_attention,
                              block_kv=block_kv, interpret=True)
-    kernel_k = pallas_operands(jax.make_jaxpr(call)(q, k, v, kv_len))[1]
+    # the scalar-prefetched lengths come first, then q, k and v
+    kernel_k = pallas_operands(jax.make_jaxpr(call)(q, k, v, kv_len))[2]
     # the head dim's axis; the slots' (padded to blocks) is the other
     at = 1 if case.startswith("slots_minor") else 2
     assert kernel_k[0] == b * hkv and kernel_k[at] == d, kernel_k
@@ -268,6 +270,47 @@ def test_flash_decode_matches_ref(case):
     want = ref.attention_ref(q[:, :, None, :], k, v, causal=False,
                              kv_len=kv_len)[:, :, 0, :]
     np.testing.assert_allclose(got, want, atol=2e-3)
+
+
+# (s, d, block_kv, lengths): a length of 1, one on a block boundary, one
+# past it, and the whole cache, in either form of the cache
+STOP_CASES = {
+    "slots_minor_d160": (512, 160, 128, [1, 128, 129, 512]),
+    "row_major_d128": (64, 128, 16, [1, 16, 17, 64]),
+}
+
+
+@pytest.mark.parametrize("case", list(STOP_CASES))
+def test_flash_decode_stops_at_each_length(case):
+    """Every K/V block wholly past a row's last valid block is NaN: the
+    walk reads none of them, so the output is the clean cache's."""
+    s, d, block_kv, lens = STOP_CASES[case]
+    b, hq, hkv = len(lens), 8, 2
+    q = randn(b, hq, d)
+    k = randn(b, hkv, s, d)
+    v = randn(b, hkv, s, d)
+    kv_len = jnp.asarray(lens)
+    want = ref.attention_ref(q[:, :, None, :], k, v, causal=False,
+                             kv_len=kv_len)[:, :, 0, :]
+    for i, n in enumerate(lens):
+        past = -(-n // block_kv) * block_kv
+        k = k.at[i, :, past:].set(jnp.nan)
+        v = v.at[i, :, past:].set(jnp.nan)
+    got = ops.covenant_decode_attention(q, k, v, kv_len, block_kv=block_kv,
+                                        interpret=True)
+    np.testing.assert_allclose(got, want, atol=2e-3)
+
+
+def test_decode_blocks_read_at_decode_b32_lengths():
+    """32 sequences at 1024-6144 of 8192 slots, 8 KV heads each, as the
+    decode-b32 traffic starts them (each length one past its context): at
+    ``block_kv`` 512 the walk reads 240 of each head's 512 blocks."""
+    ctx = 1024 + np.floor((np.arange(32) + 0.5) * (6144 - 1024) / 32)
+    lens = np.repeat(ctx.astype(np.int32) + 1, 8)
+    read, total = decode_blocks_read(lens, 8192, 512)
+    assert (read, total) == (1920, 4096)
+    assert read / total == 0.46875
+    assert decode_blocks_read([0, 1, 512, 513, 9000], 1024, 512) == (7, 10)
 
 
 def test_flash_window_equals_dense_when_window_covers_all():
