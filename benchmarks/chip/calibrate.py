@@ -68,7 +68,8 @@ def main(argv=None) -> int:
     print(json.dumps({
         "workload": args.workload, "device": device, "program": program,
         "control": control, "lower": lower, "upper": upper,
-        "upper_over_lower": {t: upper[t] / lower[t] for t in taps}}))
+        "upper_over_lower": {t: upper[t] / lower[t] if lower[t] else None
+                             for t in taps}}))
     return 0
 
 

@@ -23,10 +23,13 @@ class Call:
     """The work one kernel call needs, counted from its shapes by the
     algorithm (never by what an implementation moves).  ``family`` names
     the kernel: gemm, attn, decode, ssd, or state (the SSM decode step,
-    which has no kernel)."""
+    which has no kernel).  ``peak`` is the key in ``peaks.json`` of the
+    compute peak the call is judged against: ``bf16_flops``, or
+    ``int8_ops`` for int8 operands."""
     family: str
     flops: float
     bytes: float
+    peak: str = "bf16_flops"
 
 
 def root_key(seed: int) -> jax.Array:
@@ -52,6 +55,12 @@ def normal(key: jax.Array, shape, dtype=BF16, scale: float = 1.0):
     return (scale * jax.random.normal(key, shape, F32)).astype(dtype)
 
 
+def int8(key: jax.Array, shape) -> jax.Array:
+    """Uniform over the whole int8 range, [-128, 127]."""
+    return jax.lax.bitcast_convert_type(
+        jax.random.bits(key, shape, jnp.uint8), jnp.int8)
+
+
 def fan_in(key: jax.Array, shape, dtype=BF16):
     """A weight of shape (fan_in, fan_out) with variance 1/fan_in, so that
     an input of unit variance gives an output of unit variance."""
@@ -67,7 +76,13 @@ def host_rng(seed: int, name: str) -> np.random.Generator:
 def rel_err(got: jax.Array, want: jax.Array) -> jax.Array:
     """The widest gap between a tap and its reference, over the largest
     magnitude of the reference: max |got - want| / max |want|.  A NaN
-    anywhere gives NaN, which fails every limit."""
+    anywhere gives NaN, which fails every limit.  Integer taps are
+    compared exactly: an element that differs at all counts a gap of at
+    least 1, which f32 would lose past 2**24."""
+    if jnp.issubdtype(want.dtype, jnp.integer):
+        gap = jnp.abs(got.astype(F32) - want.astype(F32))
+        gap = jnp.where(got == want, 0.0, jnp.maximum(gap, 1.0))
+        return jnp.max(gap) / jnp.max(jnp.abs(want.astype(F32)))
     got = got.astype(F32)
     want = want.astype(F32)
     return jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))

@@ -4,7 +4,9 @@ and the chip's peaks by ``device_kind``.
 Counts follow the algorithm, not the implementation, so that a later change
 to a kernel cannot move them:
 
-- GEMM: 2mnk FLOPs; bytes of A, B and C, all in the activation dtype.
+- GEMM: 2mnk FLOPs; bytes of A, B and C, all in the activation dtype
+  (bf16), or for int8 operands 1 byte an element of A and B and 4 of the
+  int32 C, judged against the int8 peak.
 - Attention: 4 Sq Sk d FLOPs per query head, halved when causal; bytes of
   Q and O at Hq heads and of K and V at Hkv heads (not repeated).
 - Decode: 4 d Hq sum(kv_len) FLOPs; bytes of the valid K and V at Hkv
@@ -22,6 +24,10 @@ import os
 from common import Call
 
 ACT_BYTES = 2          # bf16 activations and weights
+# per dtype of a GEMM's operands: bytes of an element of A and B, of C, and
+# the compute peak it is judged against
+GEMM_DTYPES = {"bf16": (ACT_BYTES, ACT_BYTES, "bf16_flops"),
+               "int8": (1, 4, "int8_ops")}
 SSD_REF_CHUNK = 256    # Mamba2's chunk_size
 
 PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -41,13 +47,18 @@ def peaks(device_kind: str) -> dict:
 
 def roofline_s(call: Call, peak: dict) -> float:
     """The least time the chip could take for ``call``: the larger of its
-    FLOPs over the bf16 peak and its bytes over the HBM bandwidth."""
-    return max(call.flops / peak["bf16_flops"],
+    FLOPs over its own compute peak and its bytes over the HBM
+    bandwidth."""
+    return max(call.flops / peak[call.peak],
                call.bytes / peak["hbm_bytes_per_s"])
 
 
-def gemm(m: int, n: int, k: int) -> Call:
-    return Call("gemm", 2.0 * m * n * k, ACT_BYTES * (m * k + k * n + m * n))
+def gemm(m: int, n: int, k: int, dtype: str = "bf16", batch: int = 1) -> Call:
+    """``batch`` independent (m, n, k) GEMMs, each with operands of its
+    own; the arguments are a ``Pass.xla_gemms`` shape."""
+    ab, c, top = GEMM_DTYPES[dtype]
+    return Call("gemm", 2.0 * batch * m * n * k,
+                batch * (ab * (m * k + k * n) + c * m * n), top)
 
 
 def attention(b: int, hq: int, hkv: int, sq: int, sk: int, d: int,

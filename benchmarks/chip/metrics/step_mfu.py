@@ -1,10 +1,11 @@
-"""step_mfu: the FLOPs that the window's passes need (all their calls, by
-the algorithm's count) over the window's length times the chip's bf16
-peak, in %."""
+"""step_mfu: the work that the window's passes need (all their calls, by
+the algorithm's count), each call's operations over the chip's peak for
+its dtype (bf16 FLOP/s, int8 OP/s), summed and over the window's length,
+in %."""
 
 
 def read(r):
-    flops = sum(w["flops"] for w in r.work.values())
-    if r.window_s <= 0 or flops <= 0:
+    busy = sum(w["peak_s"] for w in r.work.values())
+    if r.window_s <= 0 or busy <= 0:
         return None
-    return 100.0 * flops / (r.window_s * r.peak["bf16_flops"])
+    return 100.0 * busy / r.window_s

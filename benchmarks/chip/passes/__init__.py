@@ -20,8 +20,8 @@ class Pass:
     from the state after the last checked pass i, from the seed alone.
     ``check_first`` passes are checked from the start (a decode pass, whose
     state carries on); with 0 the window's last pass is checked.
-    ``xla_gemms`` are the (m, n, k) of the pass's GEMMs, for XLA's own
-    lowering of them.
+    ``xla_gemms`` are the (m, n, k, dtype, batch) of the pass's GEMMs, for
+    XLA's own lowering of them.
     """
     params: Any
     state: Any
@@ -32,6 +32,11 @@ class Pass:
     xla_gemms: list
     check_first: int = 0
     inspect: Callable | None = None
+
+
+def bf16_gemms(shapes: list) -> list:
+    """(m, n, k) GEMMs as ``xla_gemms`` holds them: bf16, one each."""
+    return [(m, n, k, "bf16", 1) for m, n, k in shapes]
 
 
 def load(kind: str):

@@ -18,7 +18,7 @@ import counts
 import reference as R
 import traffic as T
 from common import BF16, normal, subkey
-from passes import Pass
+from passes import Pass, bf16_gemms
 from passes.dense_prefill import (dims, ffn, ffn_ref, gemm_shapes,
                                   kept_layers, layer_weights, make_weights,
                                   norm, residual)
@@ -142,5 +142,5 @@ def build(cfg: dict, traffic: dict, seed: int, key, *,
         body=functools.partial(body, cfg=cfg, slots=slots,
                                interpret=interpret),
         calls=calls, reference=reference,
-        xla_gemms=gemm_shapes(cfg, b) * g["layers"],
+        xla_gemms=bf16_gemms(gemm_shapes(cfg, b)) * g["layers"],
         check_first=2, inspect=inspect)

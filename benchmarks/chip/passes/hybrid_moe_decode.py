@@ -49,7 +49,7 @@ import counts_experts
 import reference as R
 import traffic as T
 from common import BF16, F32, fan_in, normal, subkey
-from passes import Pass
+from passes import Pass, bf16_gemms
 from passes.dense_decode import read_rows, split_qkv, write_row
 from passes.ssm_decode import STATE_SCALE, state_step
 from passes.ssm_prefill import gate, split_in
@@ -390,7 +390,8 @@ def build(cfg: dict, traffic: dict, seed: int, key, *,
              "routes": jnp.zeros((2, len(kinds), b, g["top_k"]), jnp.int32)}
 
     shapes = gemm_shapes(cfg, b)
-    xla_gemms = [s for kind in kinds for s in shapes[kind] + shapes["moe"]]
+    xla_gemms = bf16_gemms(
+        [s for kind in kinds for s in shapes[kind] + shapes["moe"]])
 
     def calls(i):
         return pass_calls(cfg, b, T.decode_lengths_at(lens0, i, slots) + 1)

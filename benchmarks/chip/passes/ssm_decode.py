@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import counts
 import reference as R
 from common import BF16, F32, normal, subkey
-from passes import Pass
+from passes import Pass, bf16_gemms
 from passes.ssm_prefill import (dims, gate, gemm_shapes, kept_layers,
                                 layer_params, make_params, split_in)
 
@@ -127,5 +127,5 @@ def build(cfg: dict, traffic: dict, seed: int, key, *,
         params=params, state=state, inputs=inputs,
         body=functools.partial(body, cfg=cfg, interpret=interpret),
         calls=lambda i: calls, reference=reference,
-        xla_gemms=gemm_shapes(cfg, b) * layers, check_first=2,
+        xla_gemms=bf16_gemms(gemm_shapes(cfg, b)) * layers, check_first=2,
         inspect=inspect)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import counts
 import reference as R
 from common import BF16, F32, fan_in, normal, subkey
-from passes import Pass
+from passes import Pass, bf16_gemms
 
 # out_proj is drawn at this multiple of fan-in scale.  No norm is run, and
 # a block's output grows as the square of its input, so that 64 chained
@@ -193,4 +193,5 @@ def build(cfg: dict, traffic: dict, seed: int, key, *,
         body=functools.partial(body, cfg=cfg, traffic=traffic,
                                interpret=interpret),
         calls=lambda i: calls, reference=reference,
-        xla_gemms=gemm_shapes(cfg, m) * g["layers"], inspect=inspect)
+        xla_gemms=bf16_gemms(gemm_shapes(cfg, m)) * g["layers"],
+        inspect=inspect)

@@ -7,7 +7,9 @@ precisions, ``{"act": float8_e4m3fn, "state": bfloat16}``, one step below
 what the configuration states (bf16 activations and weights, f32 SSM
 state).  The control rounds each operand to those dtypes and computes in
 f32, as a kernel with fp8 operands and f32 accumulation would.  Callers
-run these under ``jax.default_matmul_precision("highest")``.
+run these under ``jax.default_matmul_precision("highest")``.  Int8
+operands (``int_einsum``) are multiplied exactly; their control rounds
+them to int4 first.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+BF16 = jnp.bfloat16
 CONTROL = {"act": jnp.float8_e4m3fn, "state": jnp.bfloat16}
 
 
@@ -31,6 +34,57 @@ def rnd(x: jax.Array, low: dict | None, kind: str = "act") -> jax.Array:
 
 def matmul(a: jax.Array, b: jax.Array, low=None) -> jax.Array:
     return jnp.dot(rnd(a, low), rnd(b, low))
+
+
+# the longest stretch of an int8 contraction summed in f32: every partial
+# sum is an integer of magnitude at most 512 * 2**14 = 2**23, which f32
+# holds exactly, so no order of summation rounds it
+EXACT_K = 512
+
+
+def int4(x: jax.Array) -> jax.Array:
+    """int8 values as int4 holds them at a scale of 16: each rounded to the
+    nearest of -128, -112, ..., 112."""
+    q = jnp.clip(jnp.round(x.astype(F32) / 16), -8, 7)
+    return (16 * q).astype(jnp.int8)
+
+
+def int_einsum(spec: str, a: jax.Array, b: jax.Array, low=None) -> jax.Array:
+    """``jnp.einsum(spec, a, b)`` of int8 operands with one contracted
+    axis, exactly, int32 out: the axis in stretches of ``EXACT_K``, each
+    multiplied in bf16 (which holds every int8 exactly) with f32
+    accumulation, and the stretches summed in int32.  Not the int8 MXU
+    path that the program and XLA's int8 GEMMs take.  The control first
+    rounds each operand to int4."""
+    ins, out = spec.split("->")
+    sa, sb = ins.split(",")
+    (c,) = (set(sa) & set(sb)) - set(out)
+    ia, ib = sa.index(c), sb.index(c)
+    if low is not None:
+        a, b = int4(a), int4(b)
+    total, k = 0, a.shape[ia]
+    for s in range(0, k, EXACT_K):
+        e = min(s + EXACT_K, k)
+        part = jnp.einsum(
+            spec,
+            jax.lax.slice_in_dim(a, s, e, axis=ia).astype(BF16),
+            jax.lax.slice_in_dim(b, s, e, axis=ib).astype(BF16),
+            preferred_element_type=F32)
+        total = total + part.astype(jnp.int32)
+    return total
+
+
+def requant(y: jax.Array) -> jax.Array:
+    """int32 -> int8 by a scale of each last-axis row's own (a per-token
+    dynamic scale): the row shifted right, rounding half up, by as many
+    bits as bring its largest magnitude within 8 bits, then clipped to
+    [-128, 127].  Integer arithmetic throughout, so exact everywhere."""
+    top = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
+    shift = jnp.maximum(32 - jax.lax.clz(top) - 7, 0)
+    half = jnp.where(shift > 0,
+                     jnp.left_shift(1, jnp.maximum(shift - 1, 0)), 0)
+    r = jax.lax.shift_right_arithmetic(y + half, shift)
+    return jnp.clip(r, -128, 127).astype(jnp.int8)
 
 
 def attention(q, k, v, *, causal: bool, low=None) -> jax.Array:

@@ -121,22 +121,36 @@ class CompileCounter:
             self.n += 1
 
 
+def xla_operand(key, shape, dtype: str):
+    if dtype == "int8":
+        return common.int8(key, shape)
+    return common.normal(key, shape)
+
+
 def make_xla_gemms(shapes: list, key) -> tuple:
-    """XLA's own lowering of the pass's GEMMs: one ``jnp.dot`` with f32
-    output per distinct (m, n, k), each in a named scope ``xla.<i>``, and
-    how often the pass makes each."""
+    """XLA's own lowering of the pass's GEMMs: one ``jnp.dot`` per distinct
+    shape, each in a named scope ``xla.<i>``, and how often the pass makes
+    each.  A shape is (m, n, k, dtype, batch), as ``counts.gemm`` takes
+    it: bf16 in and f32 out, or int8 in and int32 out, and ``batch`` GEMMs
+    with operands of their own, as one batched dot."""
     distinct = sorted(set(shapes), key=shapes.index)
     mult = [shapes.count(s) for s in distinct]
-    operands = [(common.normal(common.subkey(key, "xa", i), (m, k)),
-                 common.normal(common.subkey(key, "xb", i), (k, n)))
-                for i, (m, n, k) in enumerate(distinct)]
+    operands = []
+    for i, (m, n, k, dtype, batch) in enumerate(distinct):
+        lead = (batch,) if batch > 1 else ()
+        operands.append(
+            (xla_operand(common.subkey(key, "xa", i), (*lead, m, k), dtype),
+             xla_operand(common.subkey(key, "xb", i), (*lead, k, n), dtype)))
 
     @jax.jit
     def xla_gemms(operands):
         outs = []
         for i, (a, b) in enumerate(operands):
+            acc = jnp.int32 if a.dtype == jnp.int8 else jnp.float32
             with jax.named_scope(f"xla.{i}"):
-                outs.append(jnp.dot(a, b, preferred_element_type=jnp.float32))
+                outs.append(jnp.matmul(a, b, preferred_element_type=acc)
+                            if a.ndim == 3 else
+                            jnp.dot(a, b, preferred_element_type=acc))
         return outs
 
     return xla_gemms, operands, mult

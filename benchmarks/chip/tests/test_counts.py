@@ -49,6 +49,35 @@ def test_stablelm_prefill_pass_flops():
 def test_gemm_bytes_are_bf16_a_b_c():
     c = counts.gemm(4096, 7680, 5120)
     assert c.bytes == 2 * (4096 * 5120 + 5120 * 7680 + 4096 * 7680)
+    assert c.peak == "bf16_flops"
+    assert counts.roofline_s(c, V5E) == c.flops / 197e12
+
+
+def test_int8_gemm_is_one_one_four_bytes_at_the_int8_peak():
+    c = counts.gemm(384, 4096, 1024, "int8")
+    assert c.flops == 2 * 384 * 4096 * 1024
+    assert c.bytes == 384 * 1024 + 1024 * 4096 + 4 * 384 * 4096
+    assert c.peak == "int8_ops"
+    assert counts.roofline_s(c, V5E) == c.bytes / 819e9
+    # compute-bound at m = 4096: judged against 393e12, not 197e12
+    big = counts.gemm(4096, 4096, 4096, "int8")
+    assert counts.roofline_s(big, V5E) == big.flops / 393e12
+
+
+def test_batched_gemm_is_batch_gemms():
+    one = counts.gemm(384, 384, 64, "int8")
+    many = counts.gemm(384, 384, 64, "int8", 8192)
+    assert (many.flops, many.bytes, many.peak) == (
+        8192 * one.flops, 8192 * one.bytes, "int8_ops")
+    assert counts.gemm(32, 256, 128, "bf16", 1) == counts.gemm(32, 256, 128)
+
+
+def test_other_counts_are_at_the_bf16_peak():
+    for c in (counts.attention(2, 32, 8, 2048, 2048, 160, causal=True),
+              counts.decode(32, 8, 160, [1024] * 32),
+              counts.ssd(2, 2048, 80, 64, 1, 128),
+              counts.ssm_state_step(32, 80, 128, 64)):
+        assert c.peak == "bf16_flops"
 
 
 def test_stablelm_decode_floor():

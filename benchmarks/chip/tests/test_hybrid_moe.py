@@ -5,8 +5,8 @@ a run whose routing or state is broken comes out not correct, and so does
 the control at the cell's widths.
 
 ``test_passes.py`` runs every cell of ``BENCHMARK.json`` at the smoke size
-``smoke.CELLS`` gives its pass kind; this module adds the hybrid MoE kind's
-size there, as it is collected.
+``smoke.CELLS`` gives its pass kind; this module runs the hybrid MoE kind
+at the same size.
 """
 from __future__ import annotations
 
@@ -34,23 +34,7 @@ CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 SEED = 2 ** 33 + 17
 V5E = counts.peaks("TPU v5 lite")
 
-# every key of the real configuration that the pass reads, at smoke widths:
-# 3 of 8 experts held from the third on, top 3, a Mamba, attention, Mamba
-# stage
-HYBRID = {
-    "family": "hybrid_moe", "hidden_size": 256, "mamba_d_inner": 256,
-    "mamba_n_heads": 4, "mamba_d_head": 64, "mamba_d_state": 16,
-    "mamba_n_groups": 1, "mamba_in_proj_size": 2 * 256 + 2 * 16 + 4,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 64,
-    "intermediate_size": 128, "shared_intermediate_size": 256,
-    "num_local_experts": 3, "first_held_expert": 2,
-    "published": {"num_local_experts": 8}, "num_experts_per_tok": 3,
-    "attention_multiplier": 0.125, "residual_multiplier": 0.22,
-    "rms_norm_eps": 1e-5, "layer_types": ["mamba", "attention", "mamba"],
-    "num_hidden_layers": 3, "A_init_range": [1, 16], "dt_min": 0.001,
-    "dt_max": 0.1,
-}
-smoke.CELLS.setdefault("hybrid_moe_decode", (HYBRID, smoke.DECODE))
+HYBRID = smoke.HYBRID
 
 
 def config() -> dict:

@@ -45,6 +45,9 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 INSTR = re.compile(r"^%([\w.\-]+) = .*?\b([a-z][\w\-]*)\(")
 HLO_LINE = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?op_name="([^"]*)"',
                       re.M)
+HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?\b([a-z][\w\-]*)\((.*)$")
+OPERAND = re.compile(r"%([\w.\-]+)")
 PALLAS = 'custom_call_target="tpu_custom_call"'
 
 
@@ -71,7 +74,8 @@ class Reading:
     call_s: dict                  # family -> device seconds of its calls
     glue_s: float                 # device seconds of the program's glue
     harness_s: float              # device seconds of the harness's own ops
-    work: dict                    # family -> flops, bytes, roofline_s
+    work: dict                    # family -> flops, bytes, roofline_s,
+                                  # peak_s (flops over each call's peak)
     peak: dict
     xla_s: list                   # device seconds per distinct GEMM, all reps
     xla_reps: int                 # runs of the XLA GEMMs in the trace
@@ -87,8 +91,28 @@ class Reading:
 
 def op_names(hlo_text: str) -> dict[str, str]:
     """HLO instruction name -> op_name (the JAX name stack) of a compiled
-    module's text."""
-    return dict(HLO_LINE.findall(hlo_text))
+    module's text.  A copy that the compiler made of an argument (named
+    after the argument, outside the program's name stack) for one Covenant
+    call alone, every user of it in that call's scope, takes the call's
+    name: it lays out the call's operand, and is the program's glue."""
+    names = dict(HLO_LINE.findall(hlo_text))
+    users: dict[str, list[str]] = {}
+    copies = []
+    for line in hlo_text.splitlines():
+        m = HLO_INSTR.match(line)
+        if not m:
+            continue
+        instr, opcode, rest = m.groups()
+        for operand in OPERAND.findall(rest.split("metadata=")[0]):
+            users.setdefault(operand, []).append(instr)
+        if opcode == "copy" and not names.get(instr, "jit(").startswith(
+                "jit("):
+            copies.append(instr)
+    for c in copies:
+        scopes = {scope(names.get(u, "")) for u in users.get(c, [])}
+        if len(scopes) == 1 and family(next(iter(scopes))):
+            names[c] = names[users[c][0]]
+    return names
 
 
 def newest_xplane(trace_dir: str) -> str:
@@ -105,6 +129,7 @@ def events(path: str):
     from jax.profiler import ProfileData
 
     ops, modules = [], []
+    parsed = {}                 # event name -> (instr, opcode, kernel)
     for plane in ProfileData.from_file(path).planes:
         m = DEVICE_PLANE.match(plane.name)
         if not m:
@@ -113,12 +138,16 @@ def events(path: str):
         for line in plane.lines:
             if line.name == "XLA Ops":
                 for ev in line.events:
-                    im = INSTR.match(ev.name)
-                    instr, opcode = im.groups() if im else (ev.name, "?")
-                    if opcode in CONTAINERS:
+                    name = ev.name
+                    p = parsed.get(name)
+                    if p is None:
+                        im = INSTR.match(name)
+                        instr, opcode = im.groups() if im else (name, "?")
+                        p = parsed[name] = (instr, opcode, PALLAS in name)
+                    if p[1] in CONTAINERS:
                         continue
-                    ops.append(Op(instr, opcode, PALLAS in ev.name,
-                                  ev.start_ns * 1e-9, ev.end_ns * 1e-9, dev))
+                    ops.append(Op(*p, ev.start_ns * 1e-9, ev.end_ns * 1e-9,
+                                  dev))
             elif line.name == "XLA Modules":
                 modules.extend((ev.name, ev.start_ns * 1e-9,
                                 ev.end_ns * 1e-9, dev) for ev in line.events)
@@ -235,16 +264,22 @@ def read(trace_dir: str, *, work: list, passes: int, peak: dict,
     glue_s = harness_s = 0.0
     ops_s: dict[str, float] = {}
     pnames = names.get(pass_module, {})
+    seen = {}                   # instr -> (family, glue, harness, label)
     for o in win:
-        op_name = pnames.get(o.instr, "")
-        fam = family(scope(op_name))
+        a = seen.get(o.instr)
+        if a is None:
+            op_name = pnames.get(o.instr, "")
+            fam = family(scope(op_name))
+            a = seen[o.instr] = (
+                fam, not o.kernel and bool(fam or not op_name),
+                not o.kernel and bool(op_name) and not fam, label(o, pnames))
+        fam, glue, harness, lab = a
         if fam:
             call_s[fam] = call_s.get(fam, 0.0) + o.dur
-        if not o.kernel and (fam or not op_name):
+        if glue:
             glue_s += o.dur
-        elif not o.kernel:
+        elif harness:
             harness_s += o.dur
-        lab = label(o, pnames)
         ops_s[lab] = ops_s.get(lab, 0.0) + o.dur
 
     xla_s = [0.0] * len(xla_mult)
@@ -257,10 +292,11 @@ def read(trace_dir: str, *, work: list, passes: int, peak: dict,
     agg: dict[str, dict] = {}
     for c in work:
         a = agg.setdefault(c.family, {"flops": 0.0, "bytes": 0.0,
-                                      "roofline_s": 0.0})
+                                      "roofline_s": 0.0, "peak_s": 0.0})
         a["flops"] += c.flops
         a["bytes"] += c.bytes
         a["roofline_s"] += counts.roofline_s(c, peak)
+        a["peak_s"] += c.flops / peak[c.peak]
 
     return Reading(window_s=w1 - w0, busy_s=busy, passes=passes,
                    call_s=call_s, glue_s=glue_s, harness_s=harness_s,
